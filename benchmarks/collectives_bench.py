@@ -60,9 +60,10 @@ _MESH_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.core.collectives import cna_grad_sync, hierarchical_grad_sync
-    from repro.core.jax_compat import shard_map
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     x = jax.random.normal(jax.random.PRNGKey(0), (16, 8), jnp.float32)
 
     def flat(g):
@@ -70,9 +71,9 @@ _MESH_SCRIPT = textwrap.dedent("""
 
     spec = P(None, None)
     args = dict(mesh=mesh, in_specs=(spec,), out_specs=spec, check_vma=False)
-    flat_fn = jax.jit(shard_map(flat, **args))
-    hier_fn = jax.jit(shard_map(lambda g: hierarchical_grad_sync(g), **args))
-    comp_fn = jax.jit(shard_map(lambda g: cna_grad_sync(g, compress=True), **args))
+    flat_fn = jax.jit(jax.shard_map(flat, **args))
+    hier_fn = jax.jit(jax.shard_map(lambda g: hierarchical_grad_sync(g), **args))
+    comp_fn = jax.jit(jax.shard_map(lambda g: cna_grad_sync(g, compress=True), **args))
 
     want = np.asarray(flat_fn(x))
     got_h = np.asarray(hier_fn(x))

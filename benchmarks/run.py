@@ -70,8 +70,11 @@ def locks_hostlevel():
 
 
 def main() -> int:
+    from repro.launch.compile_cache import enable_compile_cache
+
     from . import common
 
+    enable_compile_cache()
     args = sys.argv[1:]
     if "--smoke" in args:
         args.remove("--smoke")

@@ -214,7 +214,17 @@ def get_config(arch_id: str) -> ModelConfig:
 
 
 def get_reduced_config(arch_id: str) -> ModelConfig:
+    return get_preset_config(arch_id, "reduced")
+
+
+def get_preset_config(arch_id: str, preset: str) -> ModelConfig:
+    """A named variant of an architecture's config: ``reduced`` (CPU smoke
+    size) or another cut its module defines, such as granite-3-8b's
+    ``one_chip``."""
     import importlib
 
     mod = importlib.import_module(f"repro.configs.{arch_module(arch_id)}")
-    return mod.reduced()
+    fn = getattr(mod, preset, None)
+    if not callable(fn):
+        raise ValueError(f"{arch_id} defines no {preset!r} config")
+    return fn()

@@ -20,7 +20,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from .jax_compat import axis_size, shard_map
 
 
 # ---------------------------------------------------------------------------
@@ -67,7 +66,7 @@ def compressed_pod_sum(g: jax.Array, *, pod_axis="pod"):
     Exact for n_pods=2 up to one quantisation; for larger rings each hop
     requantises (error grows linearly with hops — documented, bounded in
     tests)."""
-    n = axis_size(pod_axis)
+    n = jax.lax.axis_size(pod_axis)
     acc = g.astype(jnp.float32)
     send = g.astype(jnp.float32)
     idx = jax.lax.axis_index(pod_axis)
@@ -125,7 +124,7 @@ def make_pod_average(mesh: Mesh, specs: Any):
         flat_specs, _ = jax.tree.flatten(specs)
         out = []
         for x, spec in zip(flat, flat_specs):
-            fn = shard_map(
+            fn = jax.shard_map(
                 avg_leaf,
                 mesh=mesh,
                 in_specs=(spec,),

@@ -120,7 +120,7 @@ def flash_attention_bhsd(
     block_k: int = 128,
     sq_valid: int | None = None,
     skv_valid: int | None = None,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     bh, sq, hd = q.shape
     skv = k.shape[1]

@@ -1,7 +1,7 @@
 """jit-able wrapper around the flash-attention Pallas kernel.
 
 Handles layout (B,S,H,hd) <-> (B*H,S,hd), GQA head-group index mapping,
-padding to block multiples, and backend selection (interpret=True off-TPU).
+padding to block multiples, and backend selection (``repro.kernels.interpret_mode``).
 """
 
 from __future__ import annotations
@@ -11,6 +11,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .. import interpret_mode
 from .kernel import flash_attention_bhsd
 
 
@@ -24,9 +25,7 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
     return jnp.pad(x, spec)
 
 
-def _forward(q, k, v, causal, window, block_q, block_k, interpret):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+def _forward(q, k, v, causal, window, block_q, block_k):
     b, sq, h, hd = q.shape
     skv, hkv = k.shape[1], k.shape[2]
     group = h // hkv
@@ -42,22 +41,22 @@ def _forward(q, k, v, causal, window, block_q, block_k, interpret):
         group=group, causal=causal, window=window,
         block_q=bq, block_k=bk,
         sq_valid=sq, skv_valid=skv,
-        interpret=interpret,
+        interpret=interpret_mode(),
     )
     out = out[:, :sq].reshape(b, h, sq, hd)
     return jnp.transpose(out, (0, 2, 1, 3))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _fa(q, k, v, causal, window, block_q, block_k, interpret):
-    return _forward(q, k, v, causal, window, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _fa(q, k, v, causal, window, block_q, block_k):
+    return _forward(q, k, v, causal, window, block_q, block_k)
 
 
-def _fa_fwd(q, k, v, causal, window, block_q, block_k, interpret):
-    return _forward(q, k, v, causal, window, block_q, block_k, interpret), (q, k, v)
+def _fa_fwd(q, k, v, causal, window, block_q, block_k):
+    return _forward(q, k, v, causal, window, block_q, block_k), (q, k, v)
 
 
-def _fa_bwd(causal, window, block_q, block_k, interpret, res, g):
+def _fa_bwd(causal, window, block_q, block_k, res, g):
     """Backward via the pure-jnp oracle's VJP (recompute-from-inputs, the
     flash strategy).  A dedicated backward Pallas kernel is the TPU hot-path
     extension; on the training path this keeps grads exact and memory-safe."""
@@ -73,7 +72,7 @@ _fa.defvjp(_fa_fwd, _fa_bwd)
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "window", "block_q", "block_k", "interpret"),
+    static_argnames=("causal", "window", "block_q", "block_k"),
 )
 def flash_attention(
     q: jax.Array,  # (B, Sq, H, hd)
@@ -84,6 +83,5 @@ def flash_attention(
     window: int = 0,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool | None = None,
 ) -> jax.Array:
-    return _fa(q, k, v, causal, window, block_q, block_k, interpret)
+    return _fa(q, k, v, causal, window, block_q, block_k)

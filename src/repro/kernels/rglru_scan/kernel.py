@@ -5,17 +5,19 @@ out, O(1) FLOPs/byte), so the kernel's job on TPU is purely to keep the
 recurrent state resident in VMEM while streaming (a, b) tiles HBM->VMEM:
 
   * grid = (B, n_w_blocks, n_s_blocks); the sequence dimension is the
-    innermost (sequential) grid axis, so the (block_w,) state vector carries
+    innermost (sequential) grid axis, so the (1, block_w) state row carries
     across sequence tiles in VMEM scratch.
   * within a tile, a ``fori_loop`` walks block_s steps of the recurrence on
     the VPU; each step is an (8,128)-lane fused multiply-add.
   * channel blocks (block_w = 128 lanes by default) are independent, giving
     the second parallel grid axis.
+  * ``h0`` is (B, 1, W), so its block's last two dims are (1, block_w): a
+    whole unit axis and a lane-aligned width, as Mosaic requires.
 
 Contrast with the GPU formulation (warp-parallel Blelloch scan): on TPU the
 sequential-grid + VMEM-carry pattern is both simpler and optimal once the
 kernel is bandwidth-bound; the log-depth tree adds no speedup when a single
-pass already saturates HBM.  (DESIGN.md, hardware-adaptation notes.)
+pass already saturates HBM.
 """
 
 from __future__ import annotations
@@ -36,10 +38,10 @@ def _scan_kernel(a_ref, b_ref, h0_ref, o_ref, h_scr, *, block_s: int):
         h_scr[...] = h0_ref[0].astype(jnp.float32)
 
     def step(t, h):
-        a_t = a_ref[0, t, :].astype(jnp.float32)
-        b_t = b_ref[0, t, :].astype(jnp.float32)
+        a_t = a_ref[0, pl.ds(t, 1), :].astype(jnp.float32)
+        b_t = b_ref[0, pl.ds(t, 1), :].astype(jnp.float32)
         h = a_t * h + b_t
-        o_ref[0, t, :] = h.astype(o_ref.dtype)
+        o_ref[0, pl.ds(t, 1), :] = h.astype(o_ref.dtype)
         return h
 
     h_scr[...] = jax.lax.fori_loop(0, block_s, step, h_scr[...])
@@ -48,11 +50,11 @@ def _scan_kernel(a_ref, b_ref, h0_ref, o_ref, h_scr, *, block_s: int):
 def linear_scan_bsw(
     a: jax.Array,   # (B, S, W) fp32
     b: jax.Array,   # (B, S, W)
-    h0: jax.Array,  # (B, W)
+    h0: jax.Array,  # (B, 1, W)
     *,
     block_s: int = 256,
     block_w: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     bsz, s, w = a.shape
     block_s = min(block_s, s)
@@ -65,10 +67,10 @@ def linear_scan_bsw(
         in_specs=[
             pl.BlockSpec((1, block_s, block_w), lambda b, wi, si: (b, si, wi)),
             pl.BlockSpec((1, block_s, block_w), lambda b, wi, si: (b, si, wi)),
-            pl.BlockSpec((1, block_w), lambda b, wi, si: (b, wi)),
+            pl.BlockSpec((1, 1, block_w), lambda b, wi, si: (b, 0, wi)),
         ],
         out_specs=pl.BlockSpec((1, block_s, block_w), lambda b, wi, si: (b, si, wi)),
         out_shape=jax.ShapeDtypeStruct((bsz, s, w), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_w,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, block_w), jnp.float32)],
         interpret=interpret,
     )(a, b, h0)

@@ -7,10 +7,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .. import interpret_mode
 from .kernel import linear_scan_bsw
 
 
-@functools.partial(jax.jit, static_argnames=("block_s", "block_w", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_s", "block_w"))
 def linear_scan(
     a: jax.Array,
     b: jax.Array,
@@ -18,11 +19,8 @@ def linear_scan(
     *,
     block_s: int = 256,
     block_w: int = 128,
-    interpret: bool | None = None,
 ) -> jax.Array:
     """h_t = a_t h_{t-1} + b_t.  a/b: (B, S, W); h0: (B, W) -> (B, S, W) f32."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     bsz, s, w = a.shape
     bs = min(block_s, s)
     bw = min(block_w, w)
@@ -34,7 +32,8 @@ def linear_scan(
         b = jnp.pad(b, ((0, 0), (0, pad_s), (0, pad_w)))
         h0 = jnp.pad(h0, ((0, 0), (0, pad_w)))
     out = linear_scan_bsw(
-        a.astype(jnp.float32), b.astype(jnp.float32), h0.astype(jnp.float32),
-        block_s=bs, block_w=bw, interpret=interpret,
+        a.astype(jnp.float32), b.astype(jnp.float32),
+        h0.astype(jnp.float32)[:, None, :],
+        block_s=bs, block_w=bw, interpret=interpret_mode(),
     )
     return out[:, :s, :w]

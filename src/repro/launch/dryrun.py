@@ -75,9 +75,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool, *, hlo_dir: str | No
 
     mem = compiled.memory_analysis()
     print(mem)                       # proves it fits (per-device bytes)
-    from repro.core.jax_compat import cost_analysis_dict
-
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     print({k: ca[k] for k in ("flops", "bytes accessed") if k in ca})
 
     r, hc = rl.analyze(compiled, arch=arch, shape=shape, cfg=cfg, mesh_name=mesh_name, chips=chips)

@@ -17,25 +17,31 @@ the paper's admission policy avoids).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from repro.core.jax_compat import axis_types_kw
+
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``.  The model code places
+    arrays with ``with_sharding_constraint`` over logical axes
+    (``repro.models.sharding``), which ``Explicit`` axes (jax's default)
+    refuse."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), devices=devices,
+        axis_types=(AxisType.Auto,) * len(shape),
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **axis_types_kw(len(shape)))
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model_parallel: int = 1):
     """Largest mesh on the visible devices (tests / CPU examples)."""
     n = len(jax.devices())
     assert n % model_parallel == 0
-    return jax.make_mesh(
-        (n // model_parallel, model_parallel),
-        ("data", "model"),
-        **axis_types_kw(2),
-    )
+    return make_mesh((n // model_parallel, model_parallel), ("data", "model"))
 
 
 # -- hardware constants (TPU v5e per chip; see EXPERIMENTS.md §Roofline) -----

@@ -215,11 +215,9 @@ def analyze(compiled, *, arch: str, shape, cfg, mesh_name: str, chips: int):
     scale cost_analysis' byte count by the (multiplicity-aware / body-once)
     ratio of our instruction-level byte model — calibrating our model's
     absolute conventions against XLA's while keeping the loop correction."""
-    from repro.core.jax_compat import cost_analysis_dict
-
     from .hlo_analysis import analyze_hlo
 
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     ma = compiled.memory_analysis()
     text = compiled.as_text()
     hc = analyze_hlo(text, kernel_scopes=KERNEL_SCOPES)

@@ -25,6 +25,12 @@ bucketed/packed/AOT-warmed batched engine and prints wall-clock tokens/sec +
 TTFT p50/p99 — the live demo of ``repro.serving.batching``.  Add
 ``--no-batching`` to feel the difference on the per-request engine.
 
+``--preset`` picks the model config: ``reduced`` (the default, CPU-sized) or
+a cut the architecture's config module defines, such as granite-3-8b's
+``one_chip`` (published widths, 20 of 40 layers, for one TPU v5e).  With
+several devices, ``--replicas N`` places replica ``r`` and its own copy of
+the params on device ``r mod n_devices``.
+
 ``--regions N`` runs the region tier instead (jax-free): a deterministic
 diurnal multi-tenant trace (``repro.workload``) replayed through
 ``repro.region`` — N regions of simulated fleets behind the federated
@@ -41,7 +47,8 @@ import time
 import jax
 import numpy as np
 
-from repro.configs.base import get_reduced_config
+from repro.configs.base import get_preset_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import build_model
 from repro.serving.engine import DecodeEngine, Request
 from repro.serving.scheduler import CNAScheduler, FIFOScheduler
@@ -73,9 +80,13 @@ def _emit_obs(args, tracer, registry, trace_path=None):
         print(render_prometheus(registry))
 
 
-def main(argv=None) -> int:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-8b")
+    ap.add_argument("--preset", default="reduced", choices=["reduced", "one_chip"],
+                    help="model config: the CPU-sized reduced cut, or the "
+                         "architecture's one-chip depth cut at published "
+                         "widths (granite-3-8b)")
     ap.add_argument("--requests", type=int, default=24)
     ap.add_argument("--domains", type=int, default=2)
     ap.add_argument("--slots", type=int, default=4)
@@ -134,19 +145,31 @@ def main(argv=None) -> int:
                     help="register every stat surface into the unified "
                          "repro.obs.MetricsRegistry and print its "
                          "Prometheus-style rendering at exit")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
+
+
+def load_model(args):
+    """-> (cfg, model, params): the ``--arch``/``--preset`` config with
+    random params from ``--seed``, drawn by one compiled program (eager,
+    every leaf's draw compiles on its own)."""
+    cfg = get_preset_config(args.arch, args.preset)
+    model = build_model(cfg)
+    params = jax.jit(model.init)(jax.random.PRNGKey(args.seed))
+    return cfg, model, params
+
+
+def main(argv=None) -> int:
+    args = parse_args(argv)
 
     if args.regions > 0:
         return serve_region(args)
+    enable_compile_cache()
     if args.arrivals is not None:
         return serve_arrivals(args)
     if args.replicas > 1:
         return serve_fleet(args)
 
-    arch = args.arch.replace("-", "_").replace(".", "")
-    cfg = get_reduced_config(arch)
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(args.seed))
+    cfg, model, params = load_model(args)
     rng = np.random.default_rng(args.seed)
     if args.derived_homes:
         # a small pool of shared prefixes (Zipf-free uniform draw keeps the
@@ -285,34 +308,38 @@ def serve_region(args) -> int:
     return 0
 
 
-def serve_arrivals(args) -> int:
-    """The --arrivals demo: a continuous Poisson arrival process against the
-    batched (bucketed/packed/AOT-warmed) engine, wall-clock measured.  TTFT
-    is submit-to-first-token including queueing — what a serving SLO sees."""
-    arch = args.arch.replace("-", "_").replace(".", "")
-    cfg = get_reduced_config(arch)
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(args.seed))
+def arrival_workload(args, vocab: int):
+    """-> (requests, arrival ticks): ``--requests`` prompts of mixed lengths
+    from ``--seed``, each short enough to decode all ``--max-new`` tokens
+    within ``--cache-len``, arriving as a Poisson process at ``--arrivals``
+    requests per engine tick."""
     rng = np.random.default_rng(args.seed)
-    lens = rng.integers(2, args.cache_len - 1, args.requests)
+    lens = rng.integers(2, args.cache_len - args.max_new, args.requests)
     reqs = [
-        Request(rid=i, prompt=rng.integers(0, cfg.vocab, int(l)).astype(np.int32),
+        Request(rid=i, prompt=rng.integers(0, vocab, int(l)).astype(np.int32),
                 max_new=args.max_new, domain=int(rng.integers(0, args.domains)))
         for i, l in enumerate(lens)
     ]
     arrivals = np.floor(
         np.cumsum(rng.exponential(1.0 / args.arrivals, args.requests))
     ).astype(int).tolist()
+    return reqs, arrivals
 
-    batched = not args.no_batching
-    tracer, registry = _mk_obs(args)
-    t_build = time.time()
-    eng = DecodeEngine(model, params, n_slots=args.slots, cache_len=args.cache_len,
-                       scheduler=CNAScheduler(fairness_threshold=args.fairness_threshold),
-                       domain_switch_cost=args.switch_cost, batching=batched,
-                       tracer=tracer)
-    warm = time.time() - t_build  # AOT bucket traces compile in here, not below
 
+def arrivals_engine(args, model, params, tracer=None) -> DecodeEngine:
+    """The engine ``--arrivals`` drives: CNA admission, bucketed/packed
+    prefill unless ``--no-batching``.  Batched construction compiles every
+    bucket ahead of time."""
+    return DecodeEngine(model, params, n_slots=args.slots, cache_len=args.cache_len,
+                        scheduler=CNAScheduler(fairness_threshold=args.fairness_threshold),
+                        domain_switch_cost=args.switch_cost,
+                        batching=not args.no_batching, tracer=tracer)
+
+
+def drive_arrivals(eng: DecodeEngine, reqs, arrivals) -> tuple[dict, float]:
+    """Submit each request at its arrival tick and step until every one
+    retires.  -> (rid -> seconds from submit to first token, wall seconds).
+    TTFT includes queueing — what a serving SLO sees."""
     submit_at, ttft = {}, {}
     i = tick = 0
     t0 = time.time()
@@ -326,13 +353,25 @@ def serve_arrivals(args) -> int:
             if r.rid not in ttft and r.out:
                 ttft[r.rid] = time.time() - submit_at[r.rid]
         tick += 1
-    wall = time.time() - t0
+    return ttft, time.time() - t0
+
+
+def serve_arrivals(args) -> int:
+    """The --arrivals demo: a continuous Poisson arrival process against the
+    batched (bucketed/packed/AOT-warmed) engine, wall-clock measured."""
+    cfg, model, params = load_model(args)
+    reqs, arrivals = arrival_workload(args, cfg.vocab)
+    tracer, registry = _mk_obs(args)
+    t_build = time.time()
+    eng = arrivals_engine(args, model, params, tracer)
+    warm = time.time() - t_build  # AOT bucket traces compile in here, not below
+    ttft, wall = drive_arrivals(eng, reqs, arrivals)
 
     tokens = sum(len(r.out) for r in reqs)
     waits = np.array([ttft[r.rid] for r in reqs])
     cc = eng.compile_counts
     traces = cc["prefill"] + cc.get("packed_prefill", 0) + cc.get("cont_prefill", 0)
-    mode = "batched" if batched else "per-request"
+    mode = "per-request" if args.no_batching else "batched"
     print(f"[arrivals {mode}] rate={args.arrivals}/tick requests={len(reqs)} "
           f"tokens={tokens} tokens_per_sec={tokens / wall:.1f} "
           f"ttft_p50={np.percentile(waits, 50) * 1e3:.0f}ms "
@@ -345,47 +384,62 @@ def serve_arrivals(args) -> int:
     return 0
 
 
-def serve_fleet(args) -> int:
-    """The --replicas demo: N reduced-config engines behind the router."""
-    from repro.core.topology import pod
-    from repro.router import EngineReplica, ReplicaRouter, Session
-    from repro.serving.scheduler import CNAScheduler
+def fleet_sessions(args, vocab: int) -> list:
+    """``--requests`` sessions from ``--seed``: one of a few shared prefixes
+    plus a unique tail, ``--prompt-len`` tokens in all."""
+    from repro.router import Session
 
-    arch = args.arch.replace("-", "_").replace(".", "")
-    cfg = get_reduced_config(arch)
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(args.seed))
     rng = np.random.default_rng(args.seed)
     n_shared = max(2, args.prompt_len // 2)
-    shared = [rng.integers(0, cfg.vocab, n_shared).astype(np.int32)
+    shared = [rng.integers(0, vocab, n_shared).astype(np.int32)
               for _ in range(max(2, args.replicas))]
-    sessions = [
+    return [
         Session(sid=i,
                 prompt=tuple(int(t) for t in np.concatenate([
                     shared[int(rng.integers(0, len(shared)))],
-                    rng.integers(0, cfg.vocab, args.prompt_len - n_shared).astype(np.int32),
+                    rng.integers(0, vocab, args.prompt_len - n_shared).astype(np.int32),
                 ])),
                 decode_len=args.max_new)
         for i in range(args.requests)
     ]
-    tracer, registry = _mk_obs(args)
-    replicas = [
-        EngineReplica(r, DecodeEngine(
-            model, params, n_slots=args.slots, cache_len=args.cache_len,
-            scheduler=CNAScheduler(fairness_threshold=args.fairness_threshold,
-                                   topology=pod(1, args.domains)),
-            placement="nearest_spill", prefix_index=True, prefix_kv=True,
-            paging=args.paged, page_size=args.page_size,
-            domain_switch_cost=args.switch_cost, tracer=tracer,
-        ))
-        for r in range(args.replicas)
-    ]
+
+
+def build_fleet(args, model, params, devices, tracer=None):
+    """-> (router, replicas): ``--replicas`` engines behind the router, replica
+    ``r`` on ``devices[r % len(devices)]`` with its own copy of ``params``
+    and its caches there (one copy per device; replicas sharing a device
+    share it)."""
+    from repro.core.topology import pod
+    from repro.router import EngineReplica, ReplicaRouter
+
+    placed = {}
+    replicas = []
+    for r in range(args.replicas):
+        dev = devices[r % len(devices)]
+        if dev not in placed:
+            placed[dev] = jax.device_put(params, dev)
+        with jax.default_device(dev):
+            eng = DecodeEngine(
+                model, placed[dev], n_slots=args.slots, cache_len=args.cache_len,
+                scheduler=CNAScheduler(fairness_threshold=args.fairness_threshold,
+                                       topology=pod(1, args.domains)),
+                placement="nearest_spill", prefix_index=True, prefix_kv=True,
+                paging=args.paged, page_size=args.page_size,
+                domain_switch_cost=args.switch_cost, tracer=tracer,
+            )
+        replicas.append(EngineReplica(r, eng, device=dev))
     # the shared tracer nests each engine's "request" span under the router's
     # "session" span (same trace key), giving the one-trace-every-level view
     router = ReplicaRouter(replicas, sync_every=args.sync_every,
                            kv_ship=not args.no_kv_ship,
                            fissile=args.fissile, tracer=tracer)
+    return router, replicas
 
+
+def drive_fleet(router, replicas, sessions) -> float:
+    """Submit one session per router tick and step every replica until all
+    sessions retire; each session's ``out`` then holds its tokens.  Prints
+    one line per priced ship-vs-reprefill decision.  -> wall seconds."""
     t0 = time.time()
     i = done = 0
     while done < len(sessions):
@@ -410,7 +464,17 @@ def serve_fleet(args) -> int:
             for session, ttft in rep.step():
                 router.complete(session, ttft=ttft)
                 done += 1
-    wall = time.time() - t0
+    return time.time() - t0
+
+
+def serve_fleet(args) -> int:
+    """The --replicas demo: N engines behind the router, spread over the
+    visible devices."""
+    cfg, model, params = load_model(args)
+    sessions = fleet_sessions(args, cfg.vocab)
+    tracer, registry = _mk_obs(args)
+    router, replicas = build_fleet(args, model, params, jax.devices(), tracer)
+    wall = drive_fleet(router, replicas, sessions)
 
     s = router.stats
     print(f"[router] replicas={args.replicas} sessions={len(sessions)} "

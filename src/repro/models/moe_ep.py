@@ -37,7 +37,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.core.jax_compat import shard_map
 
 from .moe import _positions, moe_capacity
 from .mlp import mlp_apply
@@ -87,7 +86,7 @@ def moe_apply_ep(params: dict, x: jax.Array, cfg):
         _ep_local, cfg=cfg, axes=axes, n_ep=n_ep, e_loc=e_loc,
         c_loc=c_loc, c_rem=c_rem, c_rin=c_rin,
     )
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(

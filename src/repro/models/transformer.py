@@ -466,7 +466,11 @@ class DecoderLM:
     # -- embedding / logits ----------------------------------------------------
     def _embed(self, params, tokens, patches=None, pos_offset=0):
         cfg = self.cfg
-        x = embed_lookup(params["embed"], tokens)
+        # the residual stream runs in the config's dtype whatever the params
+        # are stored in: a float32 model given bf16 params computes in f32
+        # (each matmul promotes its weight), which is how a float32
+        # reference runs on weights too large to hold in f32
+        x = embed_lookup(params["embed"], tokens).astype(self.pb.dtype)
         x = shard(x, "batch", "seq", "embed")
         if cfg.n_patches and patches is not None:
             pe = jnp.einsum("bpd,de->bpe", patches.astype(x.dtype), params["patch_proj"])

@@ -21,6 +21,8 @@ analog of restricting the active set before scalability collapses.
 
 from __future__ import annotations
 
+import contextlib
+
 from repro.placement import AdaptiveController
 
 from .federation import ReplicaSummary
@@ -94,7 +96,7 @@ class EngineReplica:
     its internal domains, while the router only chose the *replica*.
     """
 
-    def __init__(self, rid: int, engine) -> None:
+    def __init__(self, rid: int, engine, device=None) -> None:
         if engine.prefix_index is None:
             raise ValueError(
                 "EngineReplica needs an engine with a prefix index — the "
@@ -102,6 +104,10 @@ class EngineReplica:
             )
         self.rid = rid
         self.engine = engine
+        # the jax device holding the engine's params and caches, when the
+        # fleet spreads replicas over devices: engine steps run with it as
+        # the default device, and shipped bundles land there
+        self.device = device
         self._live: dict[int, tuple] = {}  # sid -> (session, request)
 
     @property
@@ -170,19 +176,34 @@ class EngineReplica:
         references immediately.  False means the bundle was refused (no
         store, or it cannot fit this engine's cache) — the caller must fall
         back to re-prefill and book nothing."""
+        if self.device is not None:
+            import jax
+
+            payload = jax.device_put(payload, self.device)
         return self.engine.import_kv(tokens, payload)
 
     def step(self) -> list[tuple]:
         """One engine tick; returns ``(session, ttft)`` pairs for sessions
-        that retired this tick.  TTFT is engine-clock ticks from submit to
-        admission plus one (the admission's prefill emits the first token
-        on that following tick), floored at 1 — the sample the fleet
-        controller's GCR loop consumes."""
-        self.engine.step()
+        that retired this tick, their ``out`` set to the generated tokens.
+        TTFT is engine-clock ticks from submit to admission plus one (the
+        admission's prefill emits the first token on that following tick),
+        floored at 1 — the sample the fleet controller's GCR loop consumes."""
+        with self._on_device():
+            self.engine.step()
         done = []
         for sid, (session, req) in list(self._live.items()):
             if req.finish_t >= 0:
+                session.out = tuple(req.out)
                 ttft = max(0, req.admit_t - req.submit_t) + 1
                 done.append((session, ttft))
                 del self._live[sid]
         return done
+
+    def _on_device(self):
+        """Make the replica's device JAX's default while the engine works, so
+        the arrays it creates land beside its params instead of on device 0."""
+        if self.device is None:
+            return contextlib.nullcontext()
+        import jax
+
+        return jax.default_device(self.device)

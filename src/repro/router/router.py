@@ -74,6 +74,7 @@ class Session:
     local_matched: int = 0        # target replica's actual cached prefix (tokens)
     ship: ShipDecision | None = None
     fast: bool = False            # dispatched via the fissile fast path
+    out: tuple = ()               # generated tokens, once an engine replica retires it
 
     @property
     def stall(self) -> int:

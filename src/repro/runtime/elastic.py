@@ -44,11 +44,13 @@ def plan_mesh(n_devices: int, *, model_parallel: int, want_pods: int = 1):
 def make_mesh_from_plan(shape: Sequence[int], axes: Sequence[str], devices=None):
     import jax
 
+    from repro.launch.mesh import make_mesh
+
     devices = devices if devices is not None else jax.devices()
     n = 1
     for s in shape:
         n *= s
-    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices[:n])
+    return make_mesh(shape, axes, devices=devices[:n])
 
 
 @dataclass

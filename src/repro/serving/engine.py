@@ -43,6 +43,10 @@ class Request:
     # prefix-index derivation); discounts the migration stall at admission —
     # only the uncached suffix of the KV moves.
     matched_len: int = 0
+    # device row of the logits that emitted the final token (the next-token
+    # distribution after prompt + out[:-1]), set at retirement: what a
+    # logits-level check against a reference compares
+    last_logits: object = None
 
     @property
     def done(self) -> bool:
@@ -663,6 +667,7 @@ class DecodeEngine:
             past_len = int(pos_host[slot]) >= self.cache_len - 1
             if req.done or hit_eos or past_len:
                 req.finish_t = self.scheduler.now
+                req.last_logits = logits[slot]
                 deposits_before = self.kv_deposits
                 if self.prefix_kv is not None:
                     # retirement-time deposit: the slot's cache now encodes

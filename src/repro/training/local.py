@@ -66,8 +66,12 @@ def make_local_train_step(model, cfg, *, sync_every: int, lr_fn=None, **kw):
     return step
 
 
+@jax.jit
 def pod_drift(state) -> jax.Array:
-    """Max parameter divergence across pods (monitoring the staleness dial)."""
+    """Max parameter divergence across pods (monitoring the staleness dial).
+    One program: run op by op, its per-leaf pod reductions were dozens of
+    small multi-device programs in flight at once, and on the CPU backend
+    those can starve each other's collective rendezvous."""
     def leaf_drift(x):
         if not jnp.issubdtype(x.dtype, jnp.floating) or x.shape[0] < 2:
             return jnp.zeros(())

@@ -11,19 +11,15 @@ _SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax
-    try:
-        from jax.sharding import AxisType
-        mesh_kw = {"axis_types": (AxisType.Auto,) * 3}
-    except ImportError:  # older jax: meshes are Auto-only
-        mesh_kw = {}
     from repro.configs.base import SHAPES, get_reduced_config, ShapeConfig
     from repro.launch import roofline as rl
     from repro.models.registry import build_model, input_specs
+    from repro.launch.mesh import make_mesh
     from repro.models.sharding import use_mesh
     from repro.training.step import (make_train_step, state_abstract,
                                      state_logical, tree_shardings)
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"), **mesh_kw)
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     shapes = {
         "train": ShapeConfig("t", 64, 8, "train"),
         "prefill": ShapeConfig("p", 64, 8, "prefill"),

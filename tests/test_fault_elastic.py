@@ -56,6 +56,7 @@ _ELASTIC_SCRIPT = textwrap.dedent("""
     from repro.configs.base import get_reduced_config
     from repro.models.registry import build_model, synthetic_batch
     from repro.checkpoint.manager import CheckpointManager
+    from repro.launch.mesh import make_mesh
     from repro.runtime.elastic import ElasticTrainer, plan_mesh, make_mesh_from_plan
     from repro.models.sharding import use_mesh
     from repro.training.step import init_state, make_train_step, state_abstract, state_logical, tree_shardings
@@ -65,7 +66,7 @@ _ELASTIC_SCRIPT = textwrap.dedent("""
     ckpt = CheckpointManager("{root}")
 
     # phase 1: train on an 8-device mesh (2 pods x 2 data x 2 model)
-    mesh8 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh8 = make_mesh((2, 2, 2), ("pod", "data", "model"))
     step_fn = make_train_step(model, cfg, lr_fn=lambda s: 1e-3)
     with use_mesh(mesh8):
         state = init_state(model, jax.random.PRNGKey(0), cfg)

@@ -7,7 +7,6 @@ import numpy as np
 import pytest
 
 from _subproc import REPO_ROOT, run_env
-from repro.core.jax_compat import cost_analysis_dict
 from repro.launch.hlo_analysis import (
     _parse_groups,
     _wire_bytes,
@@ -20,15 +19,12 @@ def _compile(f, *args):
     return jax.jit(f).lower(*args).compile()
 
 
-_cost = cost_analysis_dict  # normalises the dict-vs-[dict] jax API drift
-
-
 def test_dot_flops_match_cost_analysis_scan_free():
     a = jnp.zeros((256, 512), jnp.float32)
     b = jnp.zeros((512, 128), jnp.float32)
     comp = _compile(lambda a, b: a @ b, a, b)
     got = analyze_hlo(comp.as_text()).flops
-    want = _cost(comp)["flops"]
+    want = comp.cost_analysis()["flops"]
     assert got == pytest.approx(want, rel=1e-6)
     assert got == pytest.approx(2 * 256 * 512 * 128, rel=1e-6)
 
@@ -48,7 +44,7 @@ def test_scan_flops_scale_with_trip_count():
     per_iter = 2 * 8 * 64 * 64
     # cost_analysis counts the body once; the analyzer must count 10x
     assert got == pytest.approx(10 * per_iter, rel=0.05)
-    assert _cost(comp)["flops"] < got
+    assert comp.cost_analysis()["flops"] < got
 
 
 def test_nested_scan_multiplicity():
@@ -117,7 +113,8 @@ def test_collectives_inside_scan_multiply():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.launch.hlo_analysis import analyze_hlo
-        mesh = jax.make_mesh((4,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("data",))
         x = jax.ShapeDtypeStruct((8, 64), jnp.float32)
         w = jax.ShapeDtypeStruct((64, 64), jnp.float32)
         def f(x, w):

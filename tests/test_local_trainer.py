@@ -10,14 +10,10 @@ _SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
-    try:
-        from jax.sharding import AxisType
-        mesh_kw = {"axis_types": (AxisType.Auto,) * 3}
-    except ImportError:  # older jax: meshes are Auto-only
-        mesh_kw = {}
     from repro.configs.base import get_reduced_config
     from repro.data.pipeline import BigramLMDataset
     from repro.models.registry import build_model
+    from repro.launch.mesh import make_mesh
     from repro.models.sharding import use_mesh
     from repro.training.local import (make_local_train_step, pod_average,
                                       pod_drift, replicate_for_pods)
@@ -27,7 +23,7 @@ _SCRIPT = textwrap.dedent("""
     cfg = get_reduced_config("granite_3_8b").replace(accum=1, vocab=64)
     model = build_model(cfg)
     ds = BigramLMDataset(cfg.vocab, seq_len=32, global_batch=8 * N_PODS, seed=0, branching=4)
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"), **mesh_kw)
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 
     with use_mesh(mesh):
         state = replicate_for_pods(init_state(model, jax.random.PRNGKey(0), cfg), N_PODS)

@@ -34,6 +34,7 @@ _EP_SCRIPT = textwrap.dedent("""
     from repro.models.common import ParamBuilder
     from repro.models.moe import declare_moe, moe_apply
     from repro.models.moe_ep import moe_apply_ep
+    from repro.launch.mesh import make_mesh
     from repro.models.sharding import use_mesh
 
     cfg = ModelConfig(name="t", family="moe", n_layers=1, d_model=32, n_heads=4,
@@ -47,7 +48,7 @@ _EP_SCRIPT = textwrap.dedent("""
     # reference: the TP (local-dispatch) layer, generous capacity, no mesh
     ref, _ = moe_apply(params, x, cfg)
 
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_mesh((4,), ("data",))
     with use_mesh(mesh):
         out, aux = jax.jit(lambda p, x: moe_apply_ep(p, x, cfg))(params, x)
     err = float(jnp.max(jnp.abs(out - ref)))

@@ -22,9 +22,10 @@ _MESH_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
     import jax
     from jax.sharding import PartitionSpec as P
+    from repro.launch.mesh import make_mesh
     from repro.models.sharding import spec_for, use_mesh
 
-    mesh = jax.make_mesh((2, 2, 4), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 4), ("pod", "data", "model"))
     with use_mesh(mesh):
         # batch maps to (pod, data); divisible
         assert spec_for((8, 128), ("batch", None)) == P(("pod", "data"), None), spec_for((8,128),("batch",None))
@@ -37,7 +38,7 @@ _MESH_SCRIPT = textwrap.dedent("""
         # vocab to model
         assert spec_for((1024, 64), ("vocab", "fsdp")) == P("model", "data")
 
-    mesh1 = jax.make_mesh((4, 4), ("data", "model"))
+    mesh1 = make_mesh((4, 4), ("data", "model"))
     with use_mesh(mesh1):
         # no pod axis: batch falls back to data alone
         assert spec_for((8, 128), ("batch", None)) == P("data", None)
