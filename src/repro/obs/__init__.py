@@ -16,7 +16,15 @@ package is that discipline applied to the repo itself:
                 ``RouterStats``, ``ShipStats``) register into as thin views
                 — no call-site API changes;
   ``export``    JSONL trace dump, Prometheus-style text rendering, and an
-                ASCII per-request flame summary.
+                ASCII per-request flame summary;
+  ``phases``    ``PhaseClock``: wall-clock totals of a host loop's tick and
+                its phases (the decode engine's admit / dispatch / wait /
+                retire), published as registry gauges and emitted as
+                ``jax.profiler`` host spans on the device trace's clock.
+
+The ``Tracer`` never reads a wall clock: its spans carry each layer's own
+deterministic clock, so a traced run replays bit-for-bit.  ``PhaseClock``
+is the one wall-clock piece; it is always on and changes no control flow.
 
 Zero-cost-off is a hard contract: every instrumentation site guards on the
 tracer's truthiness (``NULL_TRACER`` is falsy), never consumes shared RNG
@@ -26,6 +34,7 @@ tests pin it.
 """
 
 from .export import flame, render_prometheus, to_jsonl
+from .phases import PhaseClock
 from .registry import BoundedHistogram, Counter, Gauge, HistogramVector, MetricsRegistry
 from .trace import NULL_TRACER, NullTracer, Span, Tracer, trace_key
 
@@ -37,6 +46,7 @@ __all__ = [
     "MetricsRegistry",
     "NULL_TRACER",
     "NullTracer",
+    "PhaseClock",
     "Span",
     "Tracer",
     "flame",

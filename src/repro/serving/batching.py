@@ -92,17 +92,16 @@ def _import_jax():
 
 
 class CountingJit:
-    """``jax.jit`` wrapper that counts traces and calls.
+    """``jax.jit`` wrapper that counts traces.
 
     The trace counter is a Python side effect *inside* the traced function,
     so it increments exactly once per (re)trace — the compile-count
     regression tests and the serving bench pin their trace-budget claims on
-    it.  ``calls`` counts invocations (cached or not)."""
+    it."""
 
     def __init__(self, fn, **jit_kwargs):
         jax, _ = _import_jax()
         self.traces = 0
-        self.calls = 0
 
         def counted(*args, **kwargs):
             self.traces += 1
@@ -111,7 +110,6 @@ class CountingJit:
         self._fn = jax.jit(counted, **jit_kwargs)
 
     def __call__(self, *args, **kwargs):
-        self.calls += 1
         return self._fn(*args, **kwargs)
 
 

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.phases import PhaseClock
 from .batching import CountingJit, PrefillBatcher
 from .kvcache import SlotCache
 from .prefixindex import PrefixIndex
@@ -61,7 +62,9 @@ class DecodeEngine:
       * **ticks** — ``sim_time`` and every ``*_cost`` knob
         (``domain_switch_cost``, ``slot_migration_cost``) are simulated
         scheduler ticks; one ``step()`` is one tick plus any admission
-        stalls charged that tick.  Wall-clock never enters the engine.
+        stalls charged that tick.  Wall-clock never enters this
+        accounting: only ``phases`` reads it, to time each tick's host
+        phases (``register_metrics``), and nothing branches on it.
       * **tokens** — prompt/output lengths (``Request.prompt``,
         ``matched_len``) count tokens.
       * **positions** — ``prefill_positions`` / ``reused_positions`` count
@@ -234,6 +237,11 @@ class DecodeEngine:
         self.domain_switch_cost = domain_switch_cost
         self.slot_migration_cost = slot_migration_cost
         self.sim_time = 0
+        # wall-clock time of each tick's phases, always on; decode calls and
+        # the live lanes they decoded, beside them
+        self.phases = PhaseClock("engine", ("admit", "admit.wait", "dispatch", "wait", "retire"))
+        self.decode_ticks = 0
+        self.decode_lanes = 0
         # counting wrappers so compile-count tests and the serving bench can
         # pin trace budgets on either path
         self._prefill = CountingJit(model.prefill)
@@ -387,7 +395,8 @@ class DecodeEngine:
                 # _prefill_reuse just made holds the prompt's bundle, and
                 # the slot keeps one reference per page until release
                 self.slots.note_sequence(slot, self.prefix_kv.bundle(req.prompt))
-            tok = int(jnp.argmax(logits[0]))
+            with self.phases.phase("admit.wait"):
+                tok = int(jnp.argmax(logits[0]))
             req.out.append(tok)
             self.tokens = self.tokens.at[slot, 0].set(tok)
             self.active_req[slot] = req
@@ -499,7 +508,8 @@ class DecodeEngine:
             assign.append((req, slot, jnp.argmax(logits[0])))
 
         # ONE host transfer for every admitted request's first token
-        toks = jax.device_get([t for _, _, t in assign]) if assign else []
+        with self.phases.phase("admit.wait"):
+            toks = jax.device_get([t for _, _, t in assign]) if assign else []
         for (req, slot, _), tok in zip(assign, toks):
             tok = int(tok)
             req.out.append(tok)
@@ -636,6 +646,11 @@ class DecodeEngine:
         registry.gauge(f"{prefix}_sim_time", fn=lambda: self.sim_time)
         registry.gauge(f"{prefix}_active_slots", fn=lambda: len(self.active_req))
         registry.gauge(f"{prefix}_queued", fn=lambda: len(self.scheduler))
+        # wall-clock phase totals (ns) and tick counts: <prefix>_ticks,
+        # _tick_ns, _admit_ns, _admit_wait_ns, _dispatch_ns, _wait_ns, _retire_ns
+        self.phases.register_into(registry, prefix=prefix)
+        registry.gauge(f"{prefix}_decode_ticks", fn=lambda: self.decode_ticks)
+        registry.gauge(f"{prefix}_decode_lanes", fn=lambda: self.decode_lanes)
         if self._paged:
             # the memory-compaction claim as scrapeable numbers:
             # pages_total / pages_shared / pages_free / kv_bytes_held
@@ -643,23 +658,36 @@ class DecodeEngine:
 
     # -- decode ----------------------------------------------------------------
     def step(self):
-        """One engine tick: admit, one fused decode step, retire finished."""
-        self.scheduler.tick()
-        self._admit()
-        if not self.active_req:
-            self.sim_time += 1
-            return
-        logits, new_cache = self._step(self.params, self.slots.cache, self.tokens)
-        self.slots.cache = new_cache
-        self.sim_time += 1
-        # next-token feedback stays on device (the whole vector replaces
-        # self.tokens — inactive lanes carry garbage, but claim->insert
-        # overwrites a lane before it is ever decoded); the per-slot python
-        # bookkeeping below then needs exactly ONE host transfer per tick
-        # instead of two device syncs per active slot.
-        nxt = jnp.argmax(logits, axis=-1)
-        self.tokens = nxt[:, None].astype(jnp.int32)
-        nxt_host, pos_host = jax.device_get((nxt, new_cache["pos"]))
+        """One engine tick: admit, one fused decode step, retire finished.
+        Each phase is timed by ``self.phases`` (``engine.*`` host spans)."""
+        clock = self.phases
+        with clock.step():
+            with clock.phase("admit"):
+                self.scheduler.tick()
+                self._admit()
+            if not self.active_req:
+                self.sim_time += 1
+                return
+            with clock.phase("dispatch"):
+                self.decode_ticks += 1
+                self.decode_lanes += len(self.active_req)
+                logits, new_cache = self._step(self.params, self.slots.cache, self.tokens)
+                self.slots.cache = new_cache
+                self.sim_time += 1
+                # next-token feedback stays on device (the whole vector
+                # replaces self.tokens — inactive lanes carry garbage, but
+                # claim->insert overwrites a lane before it is ever decoded);
+                # the per-slot python bookkeeping then needs exactly ONE host
+                # transfer per tick instead of two device syncs per slot.
+                nxt = jnp.argmax(logits, axis=-1)
+                self.tokens = nxt[:, None].astype(jnp.int32)
+            with clock.phase("wait"):
+                nxt_host, pos_host = jax.device_get((nxt, new_cache["pos"]))
+            with clock.phase("retire"):
+                self._retire(logits, nxt_host, pos_host)
+
+    def _retire(self, logits, nxt_host, pos_host):
+        """Append each live lane's token; retire the finished requests."""
         for slot, req in list(self.active_req.items()):
             tok = int(nxt_host[slot])
             req.out.append(tok)

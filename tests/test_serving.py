@@ -491,3 +491,78 @@ def test_engine_auto_wires_controller_shedding(small_model):
     assert tel.sheds == 2
     assert tel.cross_spills == 1 and tel.sibling_spills == 0
     assert tel.migration_cycles > 0           # only the final cross-pod spill
+
+
+# -- wall-clock phases of the tick (repro.obs.PhaseClock) ---------------------
+
+PHASES_NS = ("admit", "admit_wait", "dispatch", "wait", "retire")
+
+
+@pytest.mark.parametrize("batching", [False, True])
+def test_engine_phase_counters(small_model, batching):
+    """Every tick is counted and timed; its phases fit inside it; decode
+    ticks and live lanes match what the decode call actually saw."""
+    from repro.obs import MetricsRegistry
+
+    cfg, model, params = small_model
+    eng = DecodeEngine(model, params, n_slots=3, cache_len=32, batching=batching)
+    reg = MetricsRegistry()
+    eng.register_metrics(reg)
+    lanes_seen = []
+    decode = eng._step
+
+    def spy(*args):
+        lanes_seen.append(len(eng.active_req))
+        return decode(*args)
+
+    eng._step = spy
+    for r in _requests(cfg, n=7, plen=6, max_new=4):
+        eng.submit(r)
+    n = 0
+    while len(eng.scheduler) or eng.active_req:
+        eng.step()
+        n += 1
+    for _ in range(2):          # idle ticks: counted, no decode call
+        eng.step()
+        n += 1
+    snap = reg.collect()
+    assert snap["engine_ticks"] == n
+    assert snap["engine_decode_ticks"] == len(lanes_seen) <= n - 2
+    assert snap["engine_decode_lanes"] == sum(lanes_seen) > 0
+    ns = {k: snap[f"engine_{k}_ns"] for k in PHASES_NS}
+    assert all(isinstance(v, int) and v >= 0 for v in ns.values())
+    assert ns["admit"] > 0 and ns["dispatch"] > 0 and ns["wait"] > 0
+    assert ns["admit_wait"] <= ns["admit"]
+    assert ns["admit"] + ns["dispatch"] + ns["wait"] + ns["retire"] <= snap["engine_tick_ns"]
+    assert "engine_retire_ns" in reg.render_prometheus()
+
+
+def test_engine_phases_are_profiler_host_spans(small_model, tmp_path):
+    """With a profiler running, each tick is an ``engine.step`` host span on
+    ``/host:CPU`` and every phase span lies inside its tick."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    cfg, model, params = small_model
+    eng = DecodeEngine(model, params, n_slots=2, cache_len=32, batching=True)
+    for r in _requests(cfg, n=2, plen=6, max_new=6):
+        eng.submit(r)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(3):
+            eng.step()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+             for plane in ProfileData.from_file(path).planes if plane.name == "/host:CPU"
+             for line in plane.lines for e in line.events if e.name.startswith("engine.")]
+    assert {n for n, _, _ in spans} == {"engine.step", "engine.admit", "engine.admit.wait",
+                                        "engine.dispatch", "engine.wait", "engine.retire"}
+    steps = [(a, b) for n, a, b in spans if n == "engine.step"]
+    assert len(steps) == 3
+    for n, a, b in spans:
+        assert any(x <= a and b <= y for x, y in steps), n
+    (a, b), = [(a, b) for n, a, b in spans if n == "engine.admit.wait"]
+    assert any(n == "engine.admit" and x <= a and b <= y for n, x, y in spans)
