@@ -20,7 +20,8 @@ def one_chip() -> ModelConfig:
     By ``ModelConfig.n_params`` the whole model is 8.37e9 parameters, 15.6
     GiB in bf16, which does not fit.  At 20 layers it is 4.39e9, 8.2 GiB.
     KV costs 4 KiB per token per layer (2 x 8 KV heads x 128 x bf16), so 8
-    slots x 1024 positions x 20 layers is 0.63 GiB; serving donates no
-    buffers, so a decode step holds it twice.  The remaining layers would sit
-    on further chips as pipeline stages."""
+    slots x 1024 positions x 20 layers is 0.63 GiB; the tick's decode step
+    writes it in place, but an admission's insert copies it, so it is held
+    twice for a moment.  The remaining layers would sit on further chips as
+    pipeline stages."""
     return CONFIG.replace(n_layers=20)

@@ -128,6 +128,16 @@ def _resolve(logical, dim: int, ctx: MeshContext):
     return tuple(mesh_axis) if isinstance(mesh_axis, list) else mesh_axis
 
 
+def axis_shards(logical: str, dim: int) -> int:
+    """Shards the active mesh splits a ``dim``-long ``logical`` axis into: 1
+    with no mesh, or where the rule replicates it (absent or not dividing)."""
+    ctx = current_ctx()
+    if ctx is None:
+        return 1
+    resolved = _resolve(logical, dim, ctx)
+    return 1 if resolved is None else ctx.axis_size(resolved)
+
+
 def spec_for(shape: tuple[int, ...], logical_axes: tuple[Any, ...]) -> P:
     ctx = current_ctx()
     if ctx is None:

@@ -12,9 +12,9 @@ The layer stack is compiled as a list of *segments*:
 Block kinds: ``attn`` (attention + dense FFN), ``moe`` (attention + MoE FFN),
 ``rec`` (RG-LRU recurrent block + dense FFN), ``ssd`` (Mamba-2 block).
 
-Decode keeps the KV/recurrent cache *in the scan carry* (updated with
-``dynamic_update_index_in_dim``) so XLA aliases it in place — 1x cache
-residency rather than the 2x of the xs/ys formulation.
+Decode reads the KV cache as scan xs and writes each step's one new
+position after the scan (``DecoderLM._merge_kv``); with the cache donated
+that write is in place, so one cache stays resident.
 """
 
 from __future__ import annotations
@@ -32,7 +32,7 @@ from .common import ParamBuilder, apply_rope, cross_entropy, embed_lookup, norm,
 from .mlp import declare_mlp, mlp_apply
 from .moe import declare_moe, moe_apply
 from .rglru import declare_rglru, rglru_block, rglru_block_step
-from .sharding import shard
+from .sharding import axis_shards, shard
 from .ssm import declare_ssd, ssd_block, ssd_block_step
 
 
@@ -282,8 +282,8 @@ def _scatter_rows(cache: jax.Array, new: jax.Array, start, length) -> jax.Array:
     column offsets: token t of row b lands at column ``start[b] + t``, and
     only ``t < length[b]`` commits (right-padded rows never touch the cache).
     Gather-then-select keeps this one fused ``where`` over the cache — the
-    same masked-select idiom as ``DecoderLM._merge_kv`` — so no per-row
-    dynamic slices fan out under the layer scan."""
+    masked-select idiom ``DecoderLM._merge_kv`` keeps for seq-sharded
+    caches — so no per-row dynamic slices fan out under the layer scan."""
     idx = jnp.arange(cache.shape[1])[None, :] - start[:, None]          # (B, S)
     valid = (idx >= 0) & (idx < length[:, None])
     take = jnp.clip(idx, 0, new.shape[1] - 1)[:, :, None, None]
@@ -704,36 +704,56 @@ class DecoderLM:
 
     def _merge_kv(self, old, new, pos):
         """Write the (…, B, 1, kv, hd) new-token slices into the cache at
-        ``pos`` (ring slot for SWA archs), once per step.
+        ``pos`` (the ring slot for window configs), once per step.
 
-        Implemented as a masked select over the (sharded) cache-seq axis
-        rather than dynamic_update_slice: a dynamic-index DUS on a
-        model-sharded dim makes GSPMD all-gather the whole cache to update it
-        (measured +0.42 s collective on granite decode), while iota==slot
-        select stays shard-local (each shard rewrites only its slice)."""
+        Only the new position is written, by ``dynamic_update_slice``: once
+        for a scalar ``pos``, once per lane for per-lane ``pos``.  On a
+        donated cache XLA applies them in place.  A position past the
+        cache's end clamps onto its last entry; only an idle engine lane
+        gets there (a live sequence retires first), and its lane is
+        overwritten whole when it is claimed again.  (A scatter over the
+        lanes measured twice the step time on stablelm-3b; PERF.md.)
+
+        Under a mesh that shards the cache's ``kv_seq`` axis it stays a
+        masked select over that axis: a dynamic-index write on a sharded dim
+        makes GSPMD all-gather the whole cache to update it (measured +0.42 s
+        collective on granite decode), while the ``iota == slot`` select
+        stays shard-local.  The select reads and rewrites every byte of the
+        cache, which is why it is kept only there."""
         s_max = old.shape[-3]
         slot = jnp.mod(pos, s_max) if self.cfg.window > 0 else jnp.asarray(pos)
-        seq_iota = jnp.arange(s_max)
+        new = new.astype(old.dtype)
+        if axis_shards("kv_seq", s_max) > 1:
+            seq_iota = jnp.arange(s_max)
+            if slot.ndim == 0:
+                mask = seq_iota == slot                          # (S,)
+                mask = mask[:, None, None]                       # (S, 1, 1)
+            else:
+                mask = seq_iota[None, :] == slot[:, None]        # (B, S)
+                mask = mask[..., None, None]                     # (B, S, 1, 1)
+                if old.ndim == 5:
+                    mask = mask[None]                            # (1, B, S, 1, 1)
+            return jnp.where(mask, new, old)
         if slot.ndim == 0:
-            mask = seq_iota == slot                          # (S,)
-            mask = mask[:, None, None]                       # (S, 1, 1)
-        else:
-            mask = seq_iota[None, :] == slot[:, None]        # (B, S)
-            mask = mask[..., None, None]                     # (B, S, 1, 1)
-            if old.ndim == 5:
-                mask = mask[None]                            # (1, B, S, 1, 1)
-        return jnp.where(mask, new.astype(old.dtype), old)
+            start = (0,) * (old.ndim - 3) + (slot, 0, 0)
+            return jax.lax.dynamic_update_slice(old, new, start)
+        lane_ax = old.ndim - 4
+        for b in range(slot.shape[0]):
+            start = (0,) * lane_ax + (b, slot[b], 0, 0)
+            lane = jax.lax.slice_in_dim(new, b, b + 1, axis=lane_ax)
+            old = jax.lax.dynamic_update_slice(old, lane, start)
+        return old
 
     def decode_step(self, params, cache, tokens):
         """tokens: (B, 1) -> (logits (B, Vpad), new cache).
 
-        KV caches stay *read-only inside the layer scan* (pure xs); each
-        layer emits only its new-token (k, v) slice as ys, and the cache is
-        updated with ONE in-place write per segment after the scan.  Earlier
-        designs measured on granite decode_32k: cache-in-carry -> XLA copies
-        the whole stacked cache per layer (~170 GB/token); cache-as-ys ->
-        2x cache residency (+ per-layer masked-select writes).  This one is
-        1x residency, 1x read + one slice write (EXPERIMENTS.md §Perf)."""
+        The cache is read-only inside the layer scan (pure xs): each layer
+        emits only its new-token (k, v) slice as ys, and after the scan
+        ``_merge_kv`` writes that one position per lane into each stacked
+        cache.  A caller that donates ``cache`` (the engine's tick) gets
+        the write in place: one cache resident, the cache read once by
+        attention, and a few MB written per step instead of a rewritten
+        copy (PERF.md, the model decode layer and its findings)."""
         cfg = self.cfg
         pos = cache["pos"]
         x = self._embed(params, tokens, pos_offset=pos)

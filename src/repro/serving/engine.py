@@ -243,9 +243,13 @@ class DecodeEngine:
         self.decode_ticks = 0
         self.decode_lanes = 0
         # counting wrappers so compile-count tests and the serving bench can
-        # pin trace budgets on either path
+        # pin trace budgets on either path.  The tick's decode donates the
+        # slot cache, so the one-position KV write lands in place; stepping
+        # a prefix-store entry (``_prefill_reuse``) must leave it intact —
+        # entries are shared references — so that loop has its own entry.
         self._prefill = CountingJit(model.prefill)
-        self._step = CountingJit(model.decode_step)
+        self._step = CountingJit(model.decode_step, donate_argnums=(1,))
+        self._resume = CountingJit(model.decode_step)
         # batching: the bucketed/packed prefill layer.  Raises at
         # construction for archs where right-padding is not bitwise-invisible
         # (recurrent/SSM/MoE/sliding-window/VLM) — run those with it off.
@@ -261,10 +265,15 @@ class DecodeEngine:
     def compile_counts(self) -> dict:
         """Jit trace counts per entry point: ``prefill``/``decode`` for the
         bare per-request paths, plus ``packed_prefill``/``cont_prefill``
-        when batching is on.  The regression contract: decode traces once,
-        and packed-prefill traces stay bounded by the bucket count no matter
-        how many distinct prompt lengths the workload carries."""
-        out = {"prefill": self._prefill.traces, "decode": self._step.traces}
+        when batching is on; ``decode`` sums the tick's entry and the
+        prefix-store resume loop's.  The regression contract: decode traces
+        once per cache shape, and packed-prefill traces stay bounded by the
+        bucket count no matter how many distinct prompt lengths the
+        workload carries."""
+        out = {
+            "prefill": self._prefill.traces,
+            "decode": self._step.traces + self._resume.traces,
+        }
         if self.batcher is not None:
             out["packed_prefill"] = self.batcher.packed.traces
             out["cont_prefill"] = self.batcher.cont.traces
@@ -573,7 +582,7 @@ class DecodeEngine:
             self.prefill_positions += len(prompt)
         else:
             for i in range(matched, len(prompt)):
-                logits, cache = self._step(
+                logits, cache = self._resume(
                     self.params, cache, jnp.asarray([[int(prompt[i])]], jnp.int32)
                 )
             self.prefill_positions += len(prompt) - matched
@@ -671,6 +680,7 @@ class DecodeEngine:
             with clock.phase("dispatch"):
                 self.decode_ticks += 1
                 self.decode_lanes += len(self.active_req)
+                # donates the slot cache: its old leaves are deleted here
                 logits, new_cache = self._step(self.params, self.slots.cache, self.tokens)
                 self.slots.cache = new_cache
                 self.sim_time += 1

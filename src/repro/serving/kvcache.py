@@ -174,9 +174,11 @@ class SlotCache:
     def extract(self, slot: int):
         """Inverse of ``insert``: copy ``slot``'s lane out of the batched
         pytree as a standalone (batch=1) cache, ``pos`` included as the
-        scalar the model's prefill emits.  jax arrays are immutable, so the
-        result is safe to stash (``PrefixKVStore``) or ship to another
-        engine — the retirement-time deposit path uses exactly this."""
+        scalar the model's prefill emits.  Each leaf is a slice, a fresh
+        array and not the slot cache's own (which the engine's tick
+        donates), so the result is safe to stash (``PrefixKVStore``) or ship
+        to another engine — the retirement-time deposit path uses exactly
+        this."""
         if not 0 <= slot < self.n_slots:
             raise ValueError(f"slot {slot} out of range")
         if slot not in self.owner:

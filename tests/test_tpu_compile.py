@@ -97,3 +97,26 @@ def test_one_chip_decode_step_compiles(one_chip):
     mem = compiled.memory_analysis()
     # 2 layers of bf16 weights, embedding and head: about 1.2 GB of arguments
     assert mem.argument_size_in_bytes > 1e9
+
+
+@pytest.mark.parametrize("head_dim", [128, 80])
+def test_donated_decode_step_writes_the_cache_in_place(one_chip, head_dim):
+    """With the slot cache donated, as the engine's tick donates it, the
+    decode step aliases every cache byte to its output and its temporaries
+    stay far under one cache: no second cache is made.  Head size 80 is
+    stablelm-3b's, whose cache the compiler lays out sequence-minor."""
+    from repro.configs.base import get_preset_config
+    from repro.models.registry import build_model
+
+    cfg = get_preset_config("granite_3_8b", "one_chip").replace(n_layers=2, head_dim=head_dim)
+    model = build_model(cfg)
+    place = lambda s: _sds(one_chip, s.shape, s.dtype)  # noqa: E731
+    params = jax.tree.map(place, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = jax.tree.map(place, model.cache_abstract(8, 1024))
+    cache["pos"] = _sds(one_chip, (8,), jnp.int32)
+    tokens = _sds(one_chip, (8, 1), jnp.int32)
+    compiled = jax.jit(model.decode_step, donate_argnums=(1,)).lower(params, cache, tokens).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(s.size * s.dtype.itemsize for s in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < cache_bytes / 10
