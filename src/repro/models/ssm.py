@@ -128,12 +128,21 @@ def ssd_chunked(
     return y.astype(x.dtype), s_last
 
 
-def ssd_step(x_t, dt_t, a, b_t, c_t, s_prev):
+def ssd_step(x_t, dt_t, a, b_t, c_t, s_prev, put=None):
     """One decode step.  x_t: (B,H,P); dt_t: (B,H); b_t/c_t: (B,N);
-    s_prev: (B,H,P,N) fp32 -> (y (B,H,P), s_new)."""
+    s_prev: (B,H,P,N) fp32 -> (y (B,H,P), s_new).
+
+    ``put``, where given, stores ``s_new`` and returns it read back from
+    where it was stored, and the readout ``C·s_new`` reads that.  A caller
+    that keeps the state in a stacked buffer updated in place (the decode
+    layer scan) then writes the new state straight into it: read from
+    ``s_new`` itself, the compiler computes the state beside the readout
+    into a temporary and copies that into the buffer."""
     da = jnp.exp(dt_t.astype(jnp.float32) * a.astype(jnp.float32))   # (B,H)
     inp = jnp.einsum("bhp,bn->bhpn", (x_t * dt_t[..., None]).astype(jnp.float32), b_t.astype(jnp.float32))
     s_new = da[..., None, None] * s_prev + inp
+    if put is not None:
+        s_new = put(s_new)
     y = jnp.einsum("bhpn,bn->bhp", s_new, c_t.astype(jnp.float32))
     return y.astype(x_t.dtype), s_new
 
@@ -192,8 +201,9 @@ def ssd_block(params: dict, x: jax.Array, cfg, *, intra_impl: str = "jnp", lengt
     return shard(out, "batch", "seq", "embed"), (s_last, conv_tail)
 
 
-def ssd_block_step(params: dict, x_t: jax.Array, state, cfg):
-    """Decode step.  x_t: (B,1,D); state = (s (B,H,P,N) fp32, conv (B,K-1,C))."""
+def ssd_block_step(params: dict, x_t: jax.Array, state, cfg, put=None):
+    """Decode step.  x_t: (B,1,D); state = (s (B,H,P,N) fp32, conv (B,K-1,C));
+    ``put`` as in ``ssd_step``."""
     s_prev, conv_state = state
     di, n, h, p = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
     xt = x_t[:, 0, :]
@@ -210,7 +220,7 @@ def ssd_block_step(params: dict, x_t: jax.Array, state, cfg):
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + params["dt_bias"].astype(jnp.float32))
     a = -jnp.exp(params["a_log"].astype(jnp.float32))
     xh = xin.reshape(-1, h, p)
-    y, s_new = ssd_step(xh, dt, a, b, c, s_prev)
+    y, s_new = ssd_step(xh, dt, a, b, c, s_prev, put)
     y = y + params["d_skip"].astype(jnp.float32)[None, :, None] * xh.astype(jnp.float32)
     y = y.reshape(-1, di).astype(x_t.dtype)
     y = rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(z.dtype), params["norm_w"],

@@ -17,8 +17,10 @@ token mixers of ``attn``/``moe`` and ``ssd`` blocks run under
 splits device time by layer kind.
 
 Decode reads the KV cache as scan xs and writes each step's one new
-position after the scan (``DecoderLM._merge_kv``); with the cache donated
-that write is in place, so one cache stays resident.
+position after the scan (``DecoderLM._merge_kv``).  SSD and RG-LRU state,
+which each step rewrites whole, rides in the scan's carry and is updated
+at its layer index, so no stacked copy of it comes out of the scan.  With
+the cache donated both writes are in place, so one cache stays resident.
 """
 
 from __future__ import annotations
@@ -284,8 +286,9 @@ def block_full(params, x, cfg, kind, rope_cs, *, causal=True, lengths=None):
     return shard(x, "batch", "seq", "embed"), aux, cache
 
 
-def block_step(params, x_t, cfg, kind, pos, cache):
-    """One-token decode block.  Returns (x_t, new_cache)."""
+def block_step(params, x_t, cfg, kind, pos, cache, put=None):
+    """One-token decode block.  Returns (x_t, new_cache).  ``put`` goes to
+    an ``ssd`` block's ``ssd_step``."""
     if kind in ("attn", "moe"):
         ring = cfg.window > 0
         with jax.named_scope("mixer.attn"):
@@ -302,7 +305,7 @@ def block_step(params, x_t, cfg, kind, pos, cache):
     if kind == "ssd":
         h = _norm(params, "ln1", x_t, cfg)
         with jax.named_scope("mixer.ssd"):
-            y, new_state = ssd_block_step(params["ssd"], h, cache, cfg)
+            y, new_state = ssd_block_step(params["ssd"], h, cache, cfg, put)
         x_t = _residual(x_t, y, cfg)
         if cfg.d_ff:
             x_t = _mlp_sublayer(params, x_t, cfg)
@@ -818,13 +821,20 @@ class DecoderLM:
     def decode_step(self, params, cache, tokens):
         """tokens: (B, 1) -> (logits (B, Vpad), new cache).
 
-        The cache is read-only inside the layer scan (pure xs): each layer
-        emits only its new-token (k, v) slice as ys, and after the scan
-        ``_merge_kv`` writes that one position per lane into each stacked
-        cache.  A caller that donates ``cache`` (the engine's tick) gets
-        the write in place: one cache resident, the cache read once by
-        attention, and a few MB written per step instead of a rewritten
-        copy (PERF.md, the model decode layer and its findings)."""
+        KV is read-only inside the layer scan (pure xs): each attention
+        layer emits only its new-token (k, v) slice as ys, and after the
+        scan ``_merge_kv`` writes that one position per lane into each
+        stacked cache.  State layers (``ssd``, ``rec``) rewrite their whole
+        state each step, so a scanned segment that holds one carries its
+        stacked state leaves in the scan's carry with the layer index: each
+        layer reads its slice and writes the new state back at that index,
+        and an SSD layer's readout reads its new state back from there
+        (``put``), so the compiler keeps no temporary of it.  As scan ys the
+        new state would come out as a fresh stacked buffer that XLA then
+        copies into the cache.  A caller that donates
+        ``cache`` (the engine's tick) gets both writes in place: one cache
+        resident and no copy of it (PERF.md, the model decode layer and its
+        findings)."""
         cfg = self.cfg
         pos = cache["pos"]
         x = self._embed(params, tokens, pos_offset=pos)
@@ -845,27 +855,47 @@ class DecoderLM:
                     )
                 continue
 
-            def body(xx, xs, _kinds=seg.kinds):
-                ps, cs = xs
+            # State leaves ride in the carry and are updated in place at the
+            # layer index; KV rides as xs (see the docstring).  A segment with
+            # no state kind carries only x, as it always did.
+            cs = cache[seg.name]
+            state = {j: c for j, (k, c) in enumerate(zip(seg.kinds, cs)) if k not in ("attn", "moe")}
+            kv_xs = tuple(None if j in state else c for j, c in enumerate(cs))
+            i0 = jnp.int32(0) if state else None
+
+            def body(carry, xs, _kinds=seg.kinds):
+                xx, i, state = carry
+                state = dict(state)
+                ps, kvs = xs
                 outs = []
                 for j, kind in enumerate(_kinds):
-                    xx, out = block_step(ps[j], xx, cfg, kind, pos, cs[j])
-                    if kind not in ("attn", "moe"):
-                        out = jax.tree.map(lambda n, c: n.astype(c.dtype), out, cs[j])
-                    outs.append(out)
-                return xx, tuple(outs)
+                    if j not in state:
+                        xx, out = block_step(ps[j], xx, cfg, kind, pos, kvs[j])
+                        outs.append(out)
+                        continue
+                    leaves = list(state[j])
+                    layer = tuple(jax.lax.dynamic_index_in_dim(s, i, keepdims=False) for s in leaves)
 
-            x, ys = jax.lax.scan(body, x, (p, cache[seg.name]))
-            merged = []
-            for j, kind in enumerate(seg.kinds):
-                if kind in ("attn", "moe"):
-                    merged.append(tuple(
-                        self._merge_kv(c, n, pos)
-                        for c, n in zip(cache[seg.name][j], ys[j])
-                    ))
-                else:
-                    merged.append(ys[j])
-            new_cache[seg.name] = tuple(merged)
+                    def put(s_new, leaves=leaves):
+                        # the SSD state goes into its stack before the readout
+                        # reads it back (ssm.ssd_step)
+                        leaves[0] = jax.lax.dynamic_update_index_in_dim(leaves[0], s_new, i, 0)
+                        return jax.lax.dynamic_index_in_dim(leaves[0], i, keepdims=False)
+
+                    xx, out = block_step(ps[j], xx, cfg, kind, pos, layer, put)
+                    state[j] = tuple(
+                        new if new is not old  # already written by put
+                        else jax.lax.dynamic_update_index_in_dim(old, n.astype(old.dtype), i, 0)
+                        for old, new, n in zip(state[j], leaves, out))
+                    outs.append(None)
+                return (xx, None if i is None else i + 1, state), tuple(outs)
+
+            (x, _, state), ys = jax.lax.scan(body, (x, i0, state), (p, kv_xs))
+            new_cache[seg.name] = tuple(
+                state[j] if j in state
+                else tuple(self._merge_kv(c, n, pos) for c, n in zip(cs[j], ys[j]))
+                for j in range(len(seg.kinds))
+            )
 
         logits = self._logits(params, x)
         new_cache["pos"] = pos + 1

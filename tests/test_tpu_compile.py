@@ -10,6 +10,7 @@ process may load the TPU library, and every test worker imports this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -126,9 +127,12 @@ def test_hybrid_decode_step_compiles_donated_at_32_slots(one_chip):
     """granite-4.0-h-micro's serving decode step, whole (40 layers at the
     published widths), over 32 slots of a 1024-position cache, donated as
     the engine's tick donates it: every cache byte (2.71 GB, 89% of it
-    float32 SSD state) aliases its output, and the scan's temporaries stay
-    under one more copy of the SSD state (the scan writes the new state as
-    its outputs; PERF.md)."""
+    float32 SSD state) aliases its output.  The SSD state rides in the
+    layer scan's carry and is updated in place, so no state-shaped leaf is
+    copied, and the readout reads the new state back from the carry, so no
+    layer's new state (33.5 MB) is held as a temporary either: the
+    temporaries stay under one layer's state (as scan outputs copied back
+    into the cache they were 2.50 GB)."""
     from repro.configs.base import get_config
     from repro.models.registry import build_model
 
@@ -144,7 +148,9 @@ def test_hybrid_decode_step_compiles_donated_at_32_slots(one_chip):
     state_bytes = 36 * 32 * 64 * 64 * 128 * 4
     assert 2.7e9 < cache_bytes < 2.72e9 and state_bytes / cache_bytes > 0.89
     assert mem.alias_size_in_bytes >= cache_bytes
-    assert mem.temp_size_in_bytes < 1.05 * state_bytes
+    assert mem.temp_size_in_bytes < state_bytes / 36
+    hlo = compiled.as_text()
+    assert not re.search(r"= (f32\[4,32,64,64,128\]|bf16\[4,32,3,4352\])\{[^}]*\} copy\(", hlo)
     # weights (6.38 GB) and cache, with the step's temporaries, fit one chip
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 0.75 * 16 * 2**30
 
