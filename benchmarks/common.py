@@ -118,17 +118,6 @@ def headline_registry(registry, prefix: str = "") -> None:
         _SECTION["metrics"].update(snap)
 
 
-def emit_json(payload: dict, json_path: str | None = None) -> None:
-    """Route a bench's own JSON payload: merged into the active section's
-    metrics when one is active (the section file carries it), else written
-    directly to ``json_path`` (standalone invocations, tests)."""
-    if _SECTION is not None:
-        _SECTION["metrics"].update(payload)
-    elif json_path:
-        with open(json_path, "w") as f:
-            json.dump(payload, f, indent=2, default=str)
-
-
 def ascii_plot(title: str, xs, series: dict, *, width: int = 64, height: int = 16,
                logy: bool = False):
     """Paper-style ASCII line chart: one mark per series, shared y scale.

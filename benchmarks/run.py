@@ -4,8 +4,8 @@ benches (serving scheduler, slot placement, collective schedules, roofline).
     PYTHONPATH=src python -m benchmarks.run [--smoke] [section ...]
 
 Sections: paper, locks, restriction, placement, serving, serving_prefix,
-serving_continuous, serving_paging, router, fastpath, region, obs,
-collectives, moe_ep, roofline.  Default: all.
+serving_paging, router, fastpath, region, obs, collectives, moe_ep,
+roofline.  Default: all.
 ``region`` (fleets-of-fleets under the diurnal multi-tenant trace,
 ``benchmarks/region_bench.py``) is jax-free and smoke-lane-safe.
 ``serving_prefix`` is the jax-free shared-prefix slice of the serving section
@@ -14,10 +14,6 @@ cover it; ``serving`` already includes it.  ``router`` (fleet routing on the
 jax-free discrete-event simulator) and ``obs`` (tracing overhead + the
 attribution conservation law, ``benchmarks/obs_bench.py``) are
 smoke-lane-safe as well.
-``serving_continuous`` is the continuous-batching slice (needs jax): it — and
-the full ``serving`` section — emits machine-readable ``BENCH_serving.json``
-(tokens/sec, TTFT p50/p99, prefill trace count) so the perf trajectory is
-tracked across PRs; the CI bench lane runs it at smoke scale.
 
 Every section runs inside ``benchmarks.common.bench_section`` and emits a
 ``BENCH_<section>.json`` record in one shared schema — claims, headline
@@ -82,7 +78,7 @@ def main() -> int:
     sections = args or [
         "paper", "locks", "restriction", "placement", "serving", "router",
         "fastpath", "region", "obs", "collectives", "moe_ep", "roofline",
-    ]  # "serving" subsumes serving_prefix and serving_continuous
+    ]  # "serving" subsumes serving_prefix
     t0 = time.time()
     # every section runs inside bench_section so it emits BENCH_<name>.json
     # in the shared schema (claims, headline metrics, pass/fail)
@@ -108,18 +104,12 @@ def main() -> int:
         from . import serving_bench
 
         with common.bench_section("serving"):
-            serving_bench.run_all(json_path="BENCH_serving.json")
-    else:
-        if "serving_prefix" in sections:
-            from . import serving_bench
+            serving_bench.run_all()
+    elif "serving_prefix" in sections:
+        from . import serving_bench
 
-            with common.bench_section("serving_prefix"):
-                serving_bench.shared_prefix()
-        if "serving_continuous" in sections:
-            from . import serving_bench
-
-            with common.bench_section("serving"):
-                serving_bench.continuous(json_path="BENCH_serving.json")
+        with common.bench_section("serving_prefix"):
+            serving_bench.shared_prefix()
     if "serving" in sections or "serving_paging" in sections:
         # always its own record (jax-free): the paged-KV headline must stay
         # comparable across PRs even when only the smoke lane runs

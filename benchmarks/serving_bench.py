@@ -242,134 +242,6 @@ def engine_level():
           stats["cna"] < stats["fifo"], f"{stats['cna']} vs {stats['fifo']}")
 
 
-# -- continuous batching: bucketed/packed/AOT-warmed prefill vs per-request ---
-
-
-def _drive_arrivals(eng, reqs, arrival_ticks):
-    """Drive one engine under a fixed arrival schedule (tick -> submits),
-    wall-clock timed.  Returns (wall seconds, total tokens, TTFT list) —
-    TTFT is submit-to-first-token in wall seconds, queueing included, which
-    is what a serving SLO sees."""
-    import time as _time
-
-    submit_at, ttft = {}, {}
-    i = tick = 0
-    t0 = _time.perf_counter()
-    while i < len(reqs) or len(eng.scheduler) or eng.active_req:
-        while i < len(reqs) and arrival_ticks[i] <= tick:
-            submit_at[reqs[i].rid] = _time.perf_counter()
-            eng.submit(reqs[i])
-            i += 1
-        eng.step()
-        for r in reqs:
-            if r.rid not in ttft and r.out:
-                ttft[r.rid] = _time.perf_counter() - submit_at[r.rid]
-        tick += 1
-    wall = _time.perf_counter() - t0
-    return wall, sum(len(r.out) for r in reqs), [ttft[r.rid] for r in reqs]
-
-
-def continuous(n_requests=48, n_slots=8, cache_len=64, max_new=16, rate=0.5,
-               seed=23, json_path=None):
-    """The tentpole's acceptance bench: identical Poisson arrivals through a
-    per-request engine (prefill per admission, traces paid in the serving
-    loop) and a batched one (bucketed + packed + AOT-warmed, at most one
-    packed call per step).  Reports wall-clock tokens/sec and TTFT
-    percentiles; the batched engine must emit bitwise-identical tokens while
-    doing it >= 2x faster with strictly lower p99 TTFT, and its prefill
-    trace count must stay <= log2(cache_len)."""
-    import math
-
-    import jax
-
-    from repro.configs.base import get_reduced_config
-    from repro.models.registry import build_model
-    from repro.serving.engine import DecodeEngine, Request
-
-    n_requests = smoke(n_requests, 10)
-    max_new = smoke(max_new, 4)
-    cache_len = smoke(cache_len, 32)
-    n_slots = smoke(n_slots, 4)
-
-    cfg = get_reduced_config("granite_3_8b")
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    rng = np.random.default_rng(seed)
-    lens = rng.integers(2, cache_len - 1, n_requests)
-    gaps = rng.exponential(1.0 / rate, n_requests)
-    arrivals = np.floor(np.cumsum(gaps)).astype(int).tolist()
-
-    def mk():
-        r2 = np.random.default_rng(seed + 1)
-        return [
-            Request(rid=i, prompt=r2.integers(0, cfg.vocab, int(l)).astype(np.int32),
-                    max_new=max_new, domain=i % 2)
-            for i, l in enumerate(lens)
-        ]
-
-    # batched arm first (cold CPU), per-request baseline second (warm): any
-    # cache/turbo warm-up bias then favours the baseline, so the >=2x claim
-    # is measured conservatively.
-    bat_eng = DecodeEngine(model, params, n_slots=n_slots, cache_len=cache_len,
-                           batching=True)  # AOT warm-up happens here, untimed
-    bat_reqs = mk()
-    bat_wall, bat_toks, bat_ttft = _drive_arrivals(bat_eng, bat_reqs, arrivals)
-
-    base_eng = DecodeEngine(model, params, n_slots=n_slots, cache_len=cache_len)
-    base_reqs = mk()
-    base_wall, base_toks, base_ttft = _drive_arrivals(base_eng, base_reqs, arrivals)
-
-    stats = {}
-    rows = []
-    for name, wall, toks, ttft, eng in [
-        ("per_request", base_wall, base_toks, base_ttft, base_eng),
-        ("batched", bat_wall, bat_toks, bat_ttft, bat_eng),
-    ]:
-        cc = eng.compile_counts
-        traces = cc["prefill"] + cc.get("packed_prefill", 0) + cc.get("cont_prefill", 0)
-        stats[name] = {
-            "tokens_per_sec": toks / wall,
-            "ttft_p50": float(np.percentile(ttft, 50)),
-            "ttft_p99": float(np.percentile(ttft, 99)),
-            "prefill_traces": traces,
-        }
-        rows.append([name, f"{toks / wall:.1f}", f"{np.percentile(ttft, 50) * 1e3:.0f}ms",
-                     f"{np.percentile(ttft, 99) * 1e3:.0f}ms", traces, cc["decode"]])
-    table(
-        f"continuous batching (reduced granite, {n_requests} reqs, poisson rate "
-        f"{rate}/tick, cache_len={cache_len}, {n_slots} slots, max_new={max_new})",
-        ["engine", "tokens/sec", "ttft_p50", "ttft_p99", "prefill_traces", "decode_traces"],
-        rows,
-    )
-    b, p = stats["batched"], stats["per_request"]
-    claim("continuous: batched >= 2x tokens/sec vs per-request baseline",
-          b["tokens_per_sec"] >= 2 * p["tokens_per_sec"],
-          f"{b['tokens_per_sec']:.1f} vs {p['tokens_per_sec']:.1f} tok/s")
-    claim("continuous: batched p99 TTFT strictly lower",
-          b["ttft_p99"] < p["ttft_p99"],
-          f"{b['ttft_p99'] * 1e3:.0f}ms vs {p['ttft_p99'] * 1e3:.0f}ms")
-    claim("continuous: prefill traces bounded by log2(cache_len)",
-          b["prefill_traces"] <= math.log2(cache_len),
-          f"{b['prefill_traces']} traces, log2({cache_len})={math.log2(cache_len):.0f}")
-    claim("continuous: packed outputs bitwise-equal to per-request reference",
-          all(x.out == y.out for x, y in zip(base_reqs, bat_reqs)), "")
-    payload = {
-        "config": {"n_requests": n_requests, "n_slots": n_slots,
-                   "cache_len": cache_len, "max_new": max_new, "rate": rate},
-        "engines": stats,
-        "speedup": b["tokens_per_sec"] / p["tokens_per_sec"],
-        "outputs_bitwise_equal": all(
-            x.out == y.out for x, y in zip(base_reqs, bat_reqs)
-        ),
-    }
-    # inside run.py the active bench_section carries this into
-    # BENCH_serving.json; standalone invocations still write json_path
-    common.emit_json(payload, json_path)
-    if json_path and common._SECTION is None:
-        print(f"\n[wrote {json_path}]")
-    return stats
-
-
 def tracing(n_requests=12, max_new=4, cache_len=32, n_slots=4, seed=5):
     """The zero-cost-off / bounded-overhead contract at engine level: the
     same workload through an untraced and a traced engine must emit bitwise
@@ -560,11 +432,10 @@ def paging(n_requests=600, n_prefixes=8, prefix_len=112, suffix_len=32,
     )
 
 
-def run_all(json_path=None):
+def run_all():
     # NB: paging() is not called here — run.py gives it its own
     # bench_section so BENCH_serving_paging.json is always a separate record
     policy_level()
     shared_prefix()
     engine_level()
-    continuous(json_path=json_path)
     tracing()

@@ -21,9 +21,8 @@ one step; the ``[router]`` line reports ``fast_dispatches``).
 
 ``--arrivals RATE`` switches the driver to a continuous Poisson arrival
 process (RATE requests per engine tick, mixed prompt lengths) against the
-bucketed/packed/AOT-warmed batched engine and prints wall-clock tokens/sec +
-TTFT p50/p99 — the live demo of ``repro.serving.batching``.  Add
-``--no-batching`` to feel the difference on the per-request engine.
+bucketed/packed/AOT-warmed engine and prints wall-clock tokens/sec +
+TTFT p50/p99 — the live demo of ``repro.serving.batching``.
 
 ``--preset`` picks the model config: ``reduced`` (the default, CPU-sized) or
 a cut the architecture's config module defines, such as granite-3-8b's
@@ -127,9 +126,6 @@ def parse_args(argv=None) -> argparse.Namespace:
                     help="drive a continuous Poisson arrival process at RATE "
                          "requests/tick (mixed prompt lengths) and print "
                          "tokens/sec + TTFT p50/p99")
-    ap.add_argument("--no-batching", action="store_true",
-                    help="with --arrivals: use the per-request prefill engine "
-                         "instead of the bucketed/packed batched one")
     ap.add_argument("--paged", action="store_true",
                     help="back the engine's KV with the refcounted page "
                          "table (copy-on-write prefix sharing); dense-"
@@ -328,12 +324,10 @@ def arrival_workload(args, vocab: int):
 
 def arrivals_engine(args, model, params, tracer=None) -> DecodeEngine:
     """The engine ``--arrivals`` drives: CNA admission, bucketed/packed
-    prefill unless ``--no-batching``.  Batched construction compiles every
-    bucket ahead of time."""
+    prefill.  Construction compiles every bucket ahead of time."""
     return DecodeEngine(model, params, n_slots=args.slots, cache_len=args.cache_len,
                         scheduler=CNAScheduler(fairness_threshold=args.fairness_threshold),
-                        domain_switch_cost=args.switch_cost,
-                        batching=not args.no_batching, tracer=tracer)
+                        domain_switch_cost=args.switch_cost, tracer=tracer)
 
 
 def drive_arrivals(eng: DecodeEngine, reqs, arrivals) -> tuple[dict, float]:
@@ -358,7 +352,7 @@ def drive_arrivals(eng: DecodeEngine, reqs, arrivals) -> tuple[dict, float]:
 
 def serve_arrivals(args) -> int:
     """The --arrivals demo: a continuous Poisson arrival process against the
-    batched (bucketed/packed/AOT-warmed) engine, wall-clock measured."""
+    engine (bucketed/packed/AOT-warmed), wall-clock measured."""
     cfg, model, params = load_model(args)
     reqs, arrivals = arrival_workload(args, cfg.vocab)
     tracer, registry = _mk_obs(args)
@@ -370,9 +364,8 @@ def serve_arrivals(args) -> int:
     tokens = sum(len(r.out) for r in reqs)
     waits = np.array([ttft[r.rid] for r in reqs])
     cc = eng.compile_counts
-    traces = cc["prefill"] + cc.get("packed_prefill", 0) + cc.get("cont_prefill", 0)
-    mode = "per-request" if args.no_batching else "batched"
-    print(f"[arrivals {mode}] rate={args.arrivals}/tick requests={len(reqs)} "
+    traces = sum(n for entry, n in cc.items() if entry != "decode")
+    print(f"[arrivals] rate={args.arrivals}/tick requests={len(reqs)} "
           f"tokens={tokens} tokens_per_sec={tokens / wall:.1f} "
           f"ttft_p50={np.percentile(waits, 50) * 1e3:.0f}ms "
           f"ttft_p99={np.percentile(waits, 99) * 1e3:.0f}ms "

@@ -130,8 +130,8 @@ class PrefillBatcher:
                 "this arch cannot take the packed-prefill path (RG-LRU state, "
                 "MoE capacity routing, sliding-window rings or patch prefixes "
                 "absorb padded positions, or a bucket would leave the attn_xla "
-                "dispatch of the per-request reference); run the engine with "
-                "batching off"
+                "dispatch of the per-request reference); DecodeEngine "
+                "prefills this model one request at a time"
             )
         jax, jnp = _import_jax()
         self.model = model

@@ -1,8 +1,10 @@
 """Continuous-batching decode engine.
 
 One jit'd ``decode_step`` advances all active slots in one fused step
-(per-slot positions); prefill runs per admitted request and its cache is
-spliced into the claimed slot.  The admission order between waiting requests
+(per-slot positions).  Admission prefills each tick's granted requests in
+one bucketed, packed call (``repro.serving.batching``) and scatters each
+row into its claimed slot; families the packed-prefill gate refuses are
+prefilled one request at a time.  The admission order between waiting requests
 is delegated to the scheduler (CNA or FIFO) — the engine reports its current
 locality domain so the scheduler can apply the paper's same-socket
 preference.
@@ -72,16 +74,22 @@ class DecodeEngine:
         equal its token count, but the counters aggregate across requests
         and are the unit reuse claims are pinned in.
 
+    Admission follows the model's gate, ``supports_packed_prefill``: where
+    it holds, each ``step()`` makes at most one packed prefill call (plus
+    one continuation call with a prefix store) through a ``PrefillBatcher``
+    warmed at construction, so the jit trace count is bounded by the
+    bucket count and no trace lands in the serving loop.  Where it does
+    not, each granted request is prefilled alone, and the engine takes no
+    prefix store and no paging.
+
     Optional subsystems (all default off): ``placement`` makes the slot
     cache NUMA-homed over the scheduler's topology; ``prefix_index``
     derives ``domain=None`` homes from cached prefixes; ``prefix_kv``
     resumes prefill from stored caches, deposits retiring conversations
     back, and gives the router something to ship (``export_kv`` /
-    ``import_kv``); ``batching`` routes admission through the bucketed /
-    packed / AOT-warmed prefill layer (``repro.serving.batching``) — at
-    most one packed prefill call per ``step()``, interleaved with running
-    decode, with jit trace count bounded by the bucket count instead of
-    growing with distinct prompt lengths."""
+    ``import_kv``); ``paging`` backs the cache with a refcounted page
+    table.  ``batching`` switches nothing: it is kept only for callers that
+    pass ``batching=True``, and ``False`` raises."""
 
     def __init__(
         self,
@@ -98,7 +106,7 @@ class DecodeEngine:
         slot_migration_cost: int = 2,
         prefix_index=None,
         prefix_kv=None,
-        batching: bool = False,
+        batching: bool = True,
         pack_width: int | None = None,
         tracer=None,  # repro.obs.Tracer | None (None => zero-cost off)
         paging: bool = False,
@@ -129,35 +137,35 @@ class DecodeEngine:
         # slot lands in (or nearest to) its KV/prefix home domain.
         if placement is not None and self.scheduler.topology is None:
             raise ValueError("placement needs a topology (e.g. CNAScheduler(topology=...))")
-        # paging: the refcounted page table under the storage tier
-        # (repro.serving.paging).  Gated exactly like packed prefill — paging
-        # shares pages between sequences by token identity, which is
-        # byte-identity only where prefill is bitwise batch-invariant (plain
-        # dense attention); recurrent/SSM/sliding-window/VLM families have no
-        # pageable kv_seq axis and keep the contiguous path.
-        self._paged = bool(paging)
-        if getattr(model, "has_recurrent_state", False) and (paging or prefix_kv):
+        if not batching:
+            raise ValueError(
+                "batching=False selects nothing: admission follows the model's "
+                "packed-prefill gate (supports_packed_prefill), packing where "
+                "it holds and prefilling per request where it does not"
+            )
+        gate = getattr(model, "supports_packed_prefill", None)
+        packable = gate is not None and gate(cache_len)
+        # paging and the prefix store share caches by token identity, which
+        # is byte identity only where packed prefill is exact; recurrent
+        # layers keep one state at one length, with no kv_seq axis to page
+        # or resume from
+        if (paging or prefix_kv is not None) and (
+                getattr(model, "has_recurrent_state", False) or not packable):
             raise ValueError(
                 f"{'paging=True' if paging else 'prefix_kv'} needs a dense-attention "
-                "cache or state snapshots: this model's recurrent (SSD/RG-LRU) "
-                "layers keep a state at one length, not KV per position, so it "
-                "cannot be paged or resumed from a stored prefix until snapshots "
-                "exist — run it with paging=False and prefix_kv=None"
+                "cache that packed prefill admits: recurrent (SSD/RG-LRU) layers "
+                "keep a state at one length, not KV per position, so they cannot be "
+                "paged or resumed from a stored prefix until state snapshots exist, "
+                "and families the packed-prefill gate refuses are served per "
+                "request only — run it with paging=False and prefix_kv=None"
             )
+        if paging and not (prefix_kv is None or prefix_kv is True):
+            raise ValueError(
+                "paging builds its own page-backed prefix store over the "
+                "slot cache's page table; pass prefix_kv=True or omit it"
+            )
+        self._paged = bool(paging)
         if paging:
-            gate = getattr(model, "supports_packed_prefill", None)
-            if gate is None or not gate(cache_len):
-                raise ValueError(
-                    "paging=True needs a plain dense-attention stack (the "
-                    "same gate as packed prefill): this model family has no "
-                    "pageable kv_seq axis or is not bitwise batch-invariant "
-                    "— run it with the contiguous path (paging=False)"
-                )
-            if not (prefix_kv is None or prefix_kv is True):
-                raise ValueError(
-                    "paging builds its own page-backed prefix store over the "
-                    "slot cache's page table; pass prefix_kv=True or omit it"
-                )
             from .paging import PagedPrefixKVStore
             from .paging_jax import PagedSlotCache
 
@@ -209,7 +217,7 @@ class DecodeEngine:
             prefix_index.occupancy = lambda: telemetry.per_domain_occupancy
         # prefix_kv: a repro.serving.PrefixKVStore (or True for a default one)
         # holding prefilled caches by prompt prefix, so a prompt extending a
-        # stored prefix resumes decode from it instead of re-prefilling —
+        # stored prefix prefills only its suffix from there —
         # prefill_positions counts positions actually computed.
         if prefix_kv is True:
             prefix_kv = PrefixKVStore()
@@ -252,38 +260,34 @@ class DecodeEngine:
         self.decode_ticks = 0
         self.decode_lanes = 0
         # counting wrappers so compile-count tests and the serving bench can
-        # pin trace budgets on either path.  The tick's decode donates the
-        # slot cache, so the one-position KV write lands in place; stepping
-        # a prefix-store entry (``_prefill_reuse``) must leave it intact —
-        # entries are shared references — so that loop has its own entry.
-        self._prefill = CountingJit(model.prefill)
+        # pin trace budgets.  The tick's decode donates the slot cache, so
+        # the one-position KV write lands in place.
         self._step = CountingJit(model.decode_step, donate_argnums=(1,))
-        self._resume = CountingJit(model.decode_step)
-        # batching: the bucketed/packed prefill layer.  Raises at
-        # construction for archs whose state absorbs right-padding
-        # (RG-LRU/MoE/sliding-window/VLM) — run those with it off.
         self.batcher = None
-        if batching:
+        if packable:
             self.batcher = PrefillBatcher(
                 model, cache_len=cache_len, pack_width=pack_width or n_slots,
             )
             # AOT: every bucket trace compiles here, none in the serving loop
             self.batcher.warm(params, cont=self.prefix_kv is not None)
+        else:
+            # the per-request fallback for families packed prefill refuses
+            # (RG-LRU, MoE, sliding windows, patches, a cache past
+            # attn_chunk); it goes once packing admits them
+            self._prefill = CountingJit(model.prefill)
 
     @property
     def compile_counts(self) -> dict:
-        """Jit trace counts per entry point: ``prefill``/``decode`` for the
-        bare per-request paths, plus ``packed_prefill``/``cont_prefill``
-        when batching is on; ``decode`` sums the tick's entry and the
-        prefix-store resume loop's.  The regression contract: decode traces
-        once per cache shape, and packed-prefill traces stay bounded by the
-        bucket count no matter how many distinct prompt lengths the
+        """Jit trace counts per entry point: ``decode`` (the tick), plus
+        ``packed_prefill``/``cont_prefill`` on a packing engine or
+        ``prefill`` on a per-request one.  The regression contract: decode
+        traces once per cache shape, and packed-prefill traces stay bounded
+        by the bucket count no matter how many distinct prompt lengths the
         workload carries."""
-        out = {
-            "prefill": self._prefill.traces,
-            "decode": self._step.traces + self._resume.traces,
-        }
-        if self.batcher is not None:
+        out = {"decode": self._step.traces}
+        if self.batcher is None:
+            out["prefill"] = self._prefill.traces
+        else:
             out["packed_prefill"] = self.batcher.packed.traces
             out["cont_prefill"] = self.batcher.cont.traces
         return out
@@ -387,151 +391,101 @@ class DecodeEngine:
         return slot
 
     def _admit(self):
+        """Admit up to ``pack_width`` grants into free slots: one packed
+        prefill call, plus one continuation call with a prefix store, so
+        prefill interleaves with running decode.  Store hits continue from
+        the stored cache (bitwise the from-scratch result).  A prompt that
+        only shares a run with a stored key is split there: the fresh pack
+        prefills and deposits the run, once per pack, and the suffix
+        continues from it.  Without a batcher, each grant is prefilled
+        alone.  All first tokens come back in one host transfer."""
+        k = self.slots.n_free
         if self.batcher is not None:
-            self._admit_packed()
-            return
-        while self.slots.n_free and len(self.scheduler):
-            req = self.scheduler.next_request()
-            if req is None:
-                break
-            slot = self._claim_and_charge(req, self.scheduler.last_admit_distance)
-            p0, r0 = self.prefill_positions, self.reused_positions
-            logits, cache = self._prefill_reuse(req.prompt, req.matched_len)
-            if self.tracer:
-                computed = self.prefill_positions - p0
-                reused = self.reused_positions - r0
-                kind = "reuse" if computed == 0 else ("cont" if reused else "fresh")
-                now = self.scheduler.now
-                self.tracer.span(
-                    "prefill", req.rid, now, now,
-                    kind=kind, computed=computed, reused=reused,
-                )
-                self.tracer.begin("decode", req.rid, now)
-            with self.phases.phase("admit.insert"):
-                self.slots.insert(slot, cache)
-            if self._paged:
-                # pin the live sequence to its pages: the deposit
-                # _prefill_reuse just made holds the prompt's bundle, and
-                # the slot keeps one reference per page until release
-                self.slots.note_sequence(slot, self.prefix_kv.bundle(req.prompt))
-            with self.phases.phase("admit.wait"):
-                tok = int(jnp.argmax(logits[0]))
-            req.out.append(tok)
-            self.tokens = self.tokens.at[slot, 0].set(tok)
-            self.active_req[slot] = req
-
-    def _admit_packed(self):
-        """Packed admission: at most one packed prefill call (plus at most
-        one continuation call when a prefix-KV store is wired) per ``step``,
-        so prefill *interleaves* with running decode instead of draining the
-        queue synchronously.  Grants beyond ``pack_width`` stay queued for
-        the next tick.  This subsumes ``_prefill_reuse`` for the batched
-        path: store hits ride the continuation pack (whole suffixes at
-        seeded positions, still bitwise the from-scratch result), boundary
-        plants ride the fresh pack as extra rows, and accounting
-        (``prefill_positions``/``reused_positions``) charges exactly what
-        the per-request path would."""
-        k = min(self.slots.n_free, self.batcher.pack_width)
+            k = min(k, self.batcher.pack_width)
         if k <= 0:
             return
         reqs = self.scheduler.next_batch(k)
         if not reqs:
             return
-        store = self.prefix_kv
         admitted = [
             (req, self._claim_and_charge(req, dist))
             for req, dist in zip(reqs, self.scheduler.last_batch_distances)
         ]
-        fresh = []   # (req, slot, boundary-plant hint)
-        cont = []    # (req, slot, matched, stored cache)
-        ready = []   # (req, slot, stored logits) — whole prompt cached
-        if store is None:
-            fresh = [(req, slot, 0) for req, slot in admitted]
-        else:
-            for req, slot in admitted:
-                reuse = store.longest(req.prompt)
-                if reuse is not None:
-                    matched, cache, logits = reuse
-                    self.reused_positions += matched
-                    if matched == len(req.prompt):
-                        with self.phases.phase("admit.insert"):
-                            self.slots.insert(slot, cache)
-                        store.put([int(t) for t in req.prompt], cache, logits)
-                        if self._paged:
-                            self.slots.note_sequence(slot, store.bundle(req.prompt))
-                        ready.append((req, slot, logits))
-                    else:
-                        cont.append((req, slot, matched, cache))
-                else:
-                    hint = max(int(req.matched_len), store.common_run(req.prompt))
-                    if hint < store.min_plant or hint > len(req.prompt):
-                        hint = 0
-                    fresh.append((req, slot, hint))
-
+        store = self.prefix_kv
         assign = []  # (req, slot, device first-token scalar)
+        rows = []    # token rows of the fresh pack
+        fresh = []   # (req, slot, row, n): req.prompt[:n] is fresh row ``row``
+        runs = {}    # boundary tokens -> its row: a pack prefills each run once
+        cont = []    # (req, slot, matched, (batch=1) cache, positions reused)
+        ready = []   # (req, slot, stored logits) — whole prompt cached
+        for req, slot in admitted:
+            if self.batcher is None:
+                logits, cache = self._prefill(
+                    self.params, {"tokens": jnp.asarray(req.prompt)[None]})
+                with self.phases.phase("admit.insert"):
+                    self.slots.insert(slot, cache)
+                self.prefill_positions += len(req.prompt)
+                self._trace_prefill(req, 0)
+                assign.append((req, slot, jnp.argmax(logits[0])))
+                continue
+            reuse = None if store is None else store.longest(req.prompt)
+            if reuse is None:
+                n, row = len(req.prompt), len(rows)
+                if store is not None:
+                    # the shared run: what the index says the home pool
+                    # holds, or the longest run against a stored key
+                    run = max(int(req.matched_len), store.common_run(req.prompt))
+                    if store.min_plant <= run < n:
+                        n = run
+                        row = runs.setdefault(tuple(int(t) for t in req.prompt[:n]), row)
+                if row == len(rows):
+                    rows.append(req.prompt[:n])
+                fresh.append((req, slot, row, n))
+                continue
+            matched, cache, logits = reuse
+            self.reused_positions += matched
+            if matched < len(req.prompt):
+                cont.append((req, slot, matched, cache, matched))
+                continue
+            with self.phases.phase("admit.insert"):
+                self.slots.insert(slot, cache)
+            self._deposit(req, slot, cache, logits)
+            ready.append((req, slot, logits))
+
         if fresh:
-            rows = [req.prompt for req, _, _ in fresh]
-            # boundary plants ride the same pack as extra rows when there is
-            # room; their positions are a replica of the full row's prefix,
-            # so they are not charged again
-            plant = []
-            for req, _, hint in fresh:
-                if hint and len(rows) < self.batcher.pack_width:
-                    plant.append((len(rows), [int(t) for t in req.prompt[:hint]]))
-                    rows.append(req.prompt[:hint])
             logits, cache = self.batcher.prefill(self.params, rows)
             nxt = jnp.argmax(logits, axis=-1)
-            for i, (req, slot, _hint) in enumerate(fresh):
-                with self.phases.phase("admit.insert"):
-                    self.slots.insert_row(slot, cache, i)
-                self.prefill_positions += len(req.prompt)
-                if self.tracer:
-                    now = self.scheduler.now
-                    self.tracer.span(
-                        "prefill", req.rid, now, now,
-                        kind="fresh", computed=len(req.prompt), reused=0,
-                    )
-                assign.append((req, slot, nxt[i]))
-                if store is not None:
-                    single = self.slots.fit_single(self.batcher.extract_row(cache, i))
-                    store.put([int(t) for t in req.prompt], single, logits[i : i + 1])
-                    if self._paged:
-                        self.slots.note_sequence(slot, store.bundle(req.prompt))
-            for i, boundary in plant:
-                single = self.slots.fit_single(self.batcher.extract_row(cache, i))
-                store.put(boundary, single, logits[i : i + 1])
+            planted = {}  # row -> its deposited (batch=1) boundary cache
+            for req, slot, i, n in fresh:
+                if n == len(req.prompt):
+                    self.prefill_positions += n
+                    assign.append((req, slot, nxt[i]))
+                    self._land(req, slot, cache, logits, i, 0)
+                elif i in planted:
+                    self.reused_positions += n
+                    cont.append((req, slot, n, planted[i], n))
+                else:
+                    # the boundary row: deposited, then continued like a hit
+                    planted[i] = self.slots.fit_single(self.batcher.extract_row(cache, i))
+                    store.put([int(t) for t in req.prompt[:n]], planted[i], logits[i : i + 1])
+                    self.prefill_positions += n
+                    cont.append((req, slot, n, planted[i], 0))
         if cont:
-            rows = [c for _, _, _, c in cont]
-            suffixes = [req.prompt[matched:] for req, _, matched, _ in cont]
-            logits, cache = self.batcher.continue_rows(self.params, rows, suffixes)
+            logits, cache = self.batcher.continue_rows(
+                self.params, [c for _, _, _, c, _ in cont],
+                [req.prompt[m:] for req, _, m, _, _ in cont])
             nxt = jnp.argmax(logits, axis=-1)
-            for i, (req, slot, matched, _c) in enumerate(cont):
-                with self.phases.phase("admit.insert"):
-                    self.slots.insert_row(slot, cache, i)
-                self.prefill_positions += len(req.prompt) - matched
-                if self.tracer:
-                    now = self.scheduler.now
-                    self.tracer.span(
-                        "prefill", req.rid, now, now, kind="cont",
-                        computed=len(req.prompt) - matched, reused=matched,
-                    )
+            for i, (req, slot, m, _, reused) in enumerate(cont):
+                self.prefill_positions += len(req.prompt) - m
                 assign.append((req, slot, nxt[i]))
-                single = self.slots.fit_single(self.batcher.extract_row(cache, i))
-                store.put([int(t) for t in req.prompt], single, logits[i : i + 1])
-                if self._paged:
-                    self.slots.note_sequence(slot, store.bundle(req.prompt))
+                self._land(req, slot, cache, logits, i, reused)
         for req, slot, logits in ready:
-            if self.tracer:
-                now = self.scheduler.now
-                self.tracer.span(
-                    "prefill", req.rid, now, now,
-                    kind="reuse", computed=0, reused=len(req.prompt),
-                )
+            self._trace_prefill(req, len(req.prompt))
             assign.append((req, slot, jnp.argmax(logits[0])))
 
         # ONE host transfer for every admitted request's first token
         with self.phases.phase("admit.wait"):
-            toks = jax.device_get([t for _, _, t in assign]) if assign else []
+            toks = jax.device_get([t for _, _, t in assign])
         for (req, slot, _), tok in zip(assign, toks):
             tok = int(tok)
             req.out.append(tok)
@@ -540,67 +494,29 @@ class DecodeEngine:
             if self.tracer:
                 self.tracer.begin("decode", req.rid, self.scheduler.now)
 
-    def _prefill_reuse(self, prompt, hint_len: int = 0):
-        """Prefill ``prompt``, resuming from the longest stored prefix cache
-        when a ``PrefixKVStore`` is wired.  A stored prefix seeds the KV
-        write position past the cached run and only the uncached suffix is
-        computed (one ``decode_step`` per suffix token — the incremental form
-        of prefill, so results match the from-scratch path exactly);
-        ``prefill_positions`` counts positions actually computed, which is
-        what makes the reuse pinnable by tests and benchmarks.
+    def _land(self, req, slot, cache, logits, i, reused):
+        """Scatter row ``i`` of a packed result into ``req``'s slot, and
+        deposit it when a store is wired."""
+        with self.phases.phase("admit.insert"):
+            self.slots.insert_row(slot, cache, i)
+        self._trace_prefill(req, reused)
+        if self.prefix_kv is not None:
+            single = self.slots.fit_single(self.batcher.extract_row(cache, i))
+            self._deposit(req, slot, single, logits[i : i + 1])
 
-        ``hint_len`` is the prefix index's ``matched_len``: when the store
-        has no entry prefix-matching this prompt but the index says the run
-        ``prompt[:hint_len]`` is hot, the prefill is split at that boundary
-        and the boundary cache deposited, so the *next* prompt sharing the
-        run resumes from it.  (Stored keys must be exact prefixes of the
-        incoming prompt; shared-system-prompt traffic diverges after the
-        common run, so without the boundary entry only whole-prompt
-        extensions would ever hit.)"""
-        store = self.prefix_kv
-        if store is None:
-            logits, cache = self._prefill(self.params, {"tokens": jnp.asarray(prompt)[None]})
-            cache["pos"] = jnp.asarray(cache["pos"], jnp.int32)
-            self.prefill_positions += len(prompt)
-            return logits, cache
-        reuse = store.longest(prompt)
-        # boundary hint: the index's matched_len (what the home pool holds)
-        # or the store's own longest common run against a stored key —
-        # whichever sees the longer shared run.  matched_len alone misses
-        # batches submitted against a cold index (homes derive at submit,
-        # before any placement taught the index).
-        if reuse is None:
-            hint_len = max(int(hint_len), store.common_run(prompt))
-            if hint_len < store.min_plant:
-                hint_len = 0
-        if reuse is not None:
-            matched, cache, logits = reuse
-            self.reused_positions += matched
-        elif 0 < hint_len <= len(prompt):
-            boundary = [int(t) for t in prompt[:hint_len]]
-            logits, cache = self._prefill(self.params, {"tokens": jnp.asarray(boundary)[None]})
-            # deposits go through fit_single so every stored entry — and
-            # every suffix decode_step below — shares one (batch=1,
-            # cache_len) shape and thus one jit trace; jax arrays are
-            # immutable, so entries hold references, not copies
-            cache = self.slots.fit_single(cache)
-            store.put(boundary, cache, logits)
-            matched = hint_len
-            self.prefill_positions += hint_len
-        else:
-            matched = 0
-        if matched == 0:
-            logits, cache = self._prefill(self.params, {"tokens": jnp.asarray(prompt)[None]})
-            cache = self.slots.fit_single(cache)
-            self.prefill_positions += len(prompt)
-        else:
-            for i in range(matched, len(prompt)):
-                logits, cache = self._resume(
-                    self.params, cache, jnp.asarray([[int(prompt[i])]], jnp.int32)
-                )
-            self.prefill_positions += len(prompt) - matched
-        store.put([int(t) for t in prompt], cache, logits)
-        return logits, cache
+    def _deposit(self, req, slot, cache, logits):
+        """Store ``req``'s prompt cache; a paged slot pins its pages."""
+        self.prefix_kv.put([int(t) for t in req.prompt], cache, logits)
+        if self._paged:
+            self.slots.note_sequence(slot, self.prefix_kv.bundle(req.prompt))
+
+    def _trace_prefill(self, req, reused):
+        if self.tracer:
+            computed = len(req.prompt) - reused
+            kind = "reuse" if computed == 0 else ("cont" if reused else "fresh")
+            now = self.scheduler.now
+            self.tracer.span("prefill", req.rid, now, now,
+                             kind=kind, computed=computed, reused=reused)
 
     # -- KV shipping (repro.router.kvship) -------------------------------------
     def export_kv(self, prompt):
@@ -624,8 +540,8 @@ class DecodeEngine:
     def import_kv(self, tokens, payload) -> bool:
         """Land a shipped prefix bundle in this engine's ``PrefixKVStore``
         so the next admission of a prompt extending ``tokens`` resumes from
-        it (the ordinary ``_prefill_reuse`` path — shipped and locally
-        prefilled caches are indistinguishable from there on).  Refuses
+        it (the ordinary continuation pack — shipped and locally prefilled
+        caches are indistinguishable from there on).  Refuses
         (returns False) when no store is wired or the shipped cache cannot
         fit this engine's ``cache_len``; the caller then re-prefills."""
         if self.prefix_kv is None:

@@ -13,8 +13,8 @@ With the real package installed the shim is bypassed entirely.  Without it,
 ``@given`` degrades to a deterministic sweep: each strategy draws from one
 seeded ``random.Random`` stream, and the test body runs ``max_examples``
 times (capped by ``HYPOTHESIS_COMPAT_MAX_EXAMPLES``, default 25, so model-
-heavy suites stay fast).  No shrinking, no database — just seeded coverage
-of the same parameter space.
+heavy suites stay fast), after every explicit ``@example``.  No shrinking,
+no database — just seeded coverage of the same parameter space.
 """
 
 from __future__ import annotations
@@ -79,21 +79,39 @@ def settings(*, max_examples: int = 20, deadline=None, **_):
     return deco
 
 
+def example(**kwargs):
+    """Records one explicit example, run before the seeded sweep (either
+    decorator order relative to ``@given``, like the real package)."""
+
+    def deco(fn):
+        fn._compat_examples = [kwargs] + getattr(fn, "_compat_examples", [])
+        return fn
+
+    return deco
+
+
 def given(**strategies):
     def deco(fn):
         def runner():
             conf = getattr(runner, "_compat_settings", None) or getattr(
                 fn, "_compat_settings", {}
             )
+            explicit = getattr(runner, "_compat_examples", []) + getattr(
+                fn, "_compat_examples", []
+            )
             n = min(conf.get("max_examples", 20), _DEFAULT_CAP)
             rng = random.Random(_SEED)
-            for i in range(n):
-                kwargs = {name: s.example(rng) for name, s in strategies.items()}
+            for i in range(len(explicit) + n):
+                if i < len(explicit):
+                    kwargs = explicit[i]
+                else:
+                    kwargs = {name: s.example(rng) for name, s in strategies.items()}
                 try:
                     fn(**kwargs)
                 except Exception as e:
                     raise AssertionError(
-                        f"falsifying example ({i + 1}/{n}): {fn.__name__}(**{kwargs!r})"
+                        f"falsifying example ({i + 1}/{len(explicit) + n}): "
+                        f"{fn.__name__}(**{kwargs!r})"
                     ) from e
 
         runner.__name__ = fn.__name__

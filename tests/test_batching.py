@@ -8,11 +8,14 @@ Three contracts pinned here:
     are bit-for-bit what the per-request ``prefill`` returns for that
     prompt alone (logits, KV over the *whole* slot cache, and pos) —
     including bucket-boundary lengths, ``cache_len - 1``, and packs mixing
-    buckets.  This is what lets the engine flip ``batching=True`` without
-    changing a single emitted token.
+    buckets.  This is what lets the engine pack every model the gate admits
+    without changing a single emitted token.
   * **compile count** — a 40-prompt mixed-length workload leaves the trace
     counters exactly where AOT warm-up put them: packed-prefill traces
-    <= log2(cache_len), one decode trace, zero per-request prefill traces.
+    <= log2(cache_len), one decode trace, no per-request prefill entry.
+
+Models the gate refuses are served one prefill per request, with no prefix
+store and no paging.
 """
 
 import dataclasses
@@ -25,9 +28,9 @@ import numpy as np
 import pytest
 
 try:
-    from hypothesis import given, settings, strategies as st
+    from hypothesis import example, given, settings, strategies as st
 except ImportError:  # container lacks hypothesis: seeded fallback
-    from _hypothesis_compat import given, settings, st
+    from _hypothesis_compat import example, given, settings, st
 
 from repro.configs.base import get_reduced_config
 from repro.models.registry import build_model
@@ -148,6 +151,10 @@ def test_packed_rows_bitwise_at_boundaries():
     lengths=st.lists(st.integers(min_value=1, max_value=CACHE_LEN - 1), min_size=1, max_size=PACK),
     seed=st.integers(min_value=0, max_value=2**16),
 )
+# single-row packs whose caches once differed from the reference: pinned
+# here so the result does not depend on a local example database
+@example(lengths=[9], seed=2348)
+@example(lengths=[6], seed=0)
 def test_packed_rows_bitwise_property(lengths, seed):
     _, _, params, batcher, _, _ = _setup()
     prompts = _prompts(lengths, seed)
@@ -218,8 +225,9 @@ def test_compile_count_bounded_on_mixed_workload():
     traces stay <= log2(cache_len) (all paid at AOT warm-up, none in the
     serving loop), decode traces exactly 1, per-request prefill never runs."""
     _, model, params, _, _, _ = _setup()
-    eng = DecodeEngine(model, params, n_slots=4, cache_len=CACHE_LEN, batching=True)
+    eng = DecodeEngine(model, params, n_slots=4, cache_len=CACHE_LEN)
     warm = dict(eng.compile_counts)
+    assert warm["packed_prefill"] == len(eng.batcher.buckets)   # warmed at construction
     assert warm["packed_prefill"] <= math.log2(CACHE_LEN)
     rng = np.random.default_rng(0)
     reqs = [
@@ -233,12 +241,29 @@ def test_compile_count_bounded_on_mixed_workload():
     assert cc["packed_prefill"] == warm["packed_prefill"]    # zero serving-loop traces
     assert cc["packed_prefill"] <= math.log2(CACHE_LEN)
     assert cc["decode"] == 1
-    assert cc["prefill"] == 0
+    assert "prefill" not in cc
 
 
 # ---------------------------------------------------------------------------
-# engine equivalence: batching=True changes schedule shape, never tokens
+# engine equivalence: packing changes schedule shape, never tokens
 # ---------------------------------------------------------------------------
+
+@functools.lru_cache(maxsize=None)
+def _jitted(model):
+    return jax.jit(model.prefill), jax.jit(model.decode_step)
+
+
+def _reference(model, params, prompt, n_new):
+    """Jitted per-request reference: prefill the prompt alone, then greedy
+    ``decode_step`` one token at a time."""
+    prefill, step = _jitted(model)
+    logits, cache = prefill(params, {"tokens": jnp.asarray(prompt)[None]})
+    out = [int(jnp.argmax(logits[0]))]
+    for _ in range(n_new - 1):
+        logits, cache = step(params, cache, jnp.asarray([[out[-1]]], jnp.int32))
+        out.append(int(jnp.argmax(logits[0])))
+    return out
+
 
 def _mixed_requests(cfg, seed, n=8, max_new=3):
     rng = np.random.default_rng(seed)
@@ -253,24 +278,20 @@ def _mixed_requests(cfg, seed, n=8, max_new=3):
 
 def test_batched_engine_matches_legacy():
     cfg, model, params, _, _, _ = _setup()
-    a = _mixed_requests(cfg, seed=3)
-    b = _mixed_requests(cfg, seed=3)
-    DecodeEngine(model, params, n_slots=4, cache_len=CACHE_LEN).run(a)
-    eng = DecodeEngine(model, params, n_slots=4, cache_len=CACHE_LEN, batching=True)
-    eng.run(b)
-    assert [r.out for r in a] == [r.out for r in b]
-    assert eng.compile_counts["prefill"] == 0
+    reqs = _mixed_requests(cfg, seed=3)
+    eng = DecodeEngine(model, params, n_slots=4, cache_len=CACHE_LEN)
+    eng.run(reqs)
+    for r in reqs:
+        assert r.out == _reference(model, params, r.prompt, len(r.out)), r.rid
+    assert "prefill" not in eng.compile_counts
 
 
 def test_batched_prefix_kv_matches_from_scratch():
     """Shared-prefix traffic over a live PrefixKVStore: full hits, partial
     hits (continuation pack) and boundary plants all active — outputs stay
-    bitwise what a *from-scratch* engine (no store) emits.  Stronger than
-    the per-request store path offers: its ``decode_step`` suffix replay
-    agrees with from-scratch prefill only to cache-dtype resolution (see
-    ``_greedy_reference_split`` in test_serving.py), so greedy argmax can
-    legitimately flip there; ``prefill_cont`` replays the exact prefill op
-    order and cannot."""
+    bitwise what a *from-scratch* engine (no store) emits: ``prefill_cont``
+    replays the exact prefill op order, where a ``decode_step`` suffix
+    replay would agree only to cache-dtype resolution."""
     cfg, model, params, _, _, _ = _setup()
 
     def mk(seed):
@@ -290,23 +311,17 @@ def test_batched_prefix_kv_matches_from_scratch():
             reqs.append(Request(rid=8 + i, prompt=ext, max_new=3, domain=i % 2))
         return reqs
 
-    a, b, c = mk(5), mk(5), mk(5)
+    a, c = mk(5), mk(5)
     scratch = DecodeEngine(model, params, n_slots=4, cache_len=2 * CACHE_LEN)
     scratch.run(a)
-    legacy = DecodeEngine(model, params, n_slots=4, cache_len=2 * CACHE_LEN, prefix_kv=True)
-    legacy.run(b)
-    bat = DecodeEngine(model, params, n_slots=4, cache_len=2 * CACHE_LEN,
-                       prefix_kv=True, batching=True)
+    bat = DecodeEngine(model, params, n_slots=4, cache_len=2 * CACHE_LEN, prefix_kv=True)
     bat.run(c)
     assert [r.out for r in a] == [r.out for r in c]
     assert bat.reused_positions > 0                          # the store actually fired
     assert bat.compile_counts["cont_prefill"] <= math.log2(2 * CACHE_LEN)
-    # reuse accounting is conserved against the per-request store path: the
-    # same total positions flow through, though the computed/resumed split
-    # may differ (a pack cannot resume from deposits made inside itself;
-    # the serial path can)
-    assert (legacy.prefill_positions + legacy.reused_positions
-            == bat.prefill_positions + bat.reused_positions)
+    # reuse accounting is conserved: every prompt position is either
+    # computed or resumed from the store, once
+    assert bat.prefill_positions + bat.reused_positions == scratch.prefill_positions
 
 
 # ---------------------------------------------------------------------------
@@ -319,8 +334,41 @@ def test_gate_refuses_non_dense_arch():
     cfg = get_reduced_config("recurrentgemma_2b")
     model = build_model(cfg)
     assert not model.supports_packed_prefill(CACHE_LEN)
-    with pytest.raises(ValueError, match="batching off"):
+    with pytest.raises(ValueError, match="one request at a time"):
         PrefillBatcher(model, cache_len=CACHE_LEN, pack_width=2)
+
+
+@pytest.mark.parametrize("arch", ["recurrentgemma_2b", "mixtral_8x22b", "pixtral_12b"])
+def test_gate_refused_arch_admits_per_request(arch):
+    """RG-LRU, MoE with sliding windows and patch-prefixed models, which
+    the gate refuses, are served one prefill per request and decode what
+    the per-request reference decodes."""
+    cfg = get_reduced_config(arch)
+    model = build_model(cfg)
+    assert not model.supports_packed_prefill(CACHE_LEN)
+    params = model.init(jax.random.PRNGKey(0))
+    eng = DecodeEngine(model, params, n_slots=2, cache_len=CACHE_LEN)
+    assert eng.batcher is None
+    rng = np.random.default_rng(11)
+    reqs = [Request(rid=i, prompt=rng.integers(0, cfg.vocab, n).astype(np.int32), max_new=4)
+            for i, n in enumerate([5, 9, 3])]
+    eng.run(reqs)
+    for r in reqs:
+        assert r.out == _reference(model, params, r.prompt, r.max_new), (arch, r.rid)
+    assert eng.compile_counts["prefill"] >= 1 and "packed_prefill" not in eng.compile_counts
+
+
+@pytest.mark.parametrize("kw", ["prefix_kv", "paging"])
+def test_gate_refused_arch_refuses_store(kw):
+    model = build_model(get_reduced_config("mixtral_8x22b"))
+    with pytest.raises(ValueError, match="served per request only"):
+        DecodeEngine(model, None, n_slots=2, cache_len=CACHE_LEN, **{kw: True})
+
+
+def test_engine_rejects_batching_false():
+    _, model, params, _, _, _ = _setup()
+    with pytest.raises(ValueError, match="packed-prefill gate"):
+        DecodeEngine(model, params, n_slots=2, cache_len=CACHE_LEN, batching=False)
 
 
 def test_gate_checks_attn_dispatch_per_bucket():

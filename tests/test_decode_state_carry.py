@@ -114,7 +114,7 @@ def test_tick_decode_donates_the_hybrid_cache():
     cfg = get_reduced_config("granite_4_0_h_micro")
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
-    eng = DecodeEngine(model, params, n_slots=3, cache_len=32, batching=True)
+    eng = DecodeEngine(model, params, n_slots=3, cache_len=32)
     rng = np.random.default_rng(0)
     for i in range(3):
         eng.submit(Request(rid=i, prompt=rng.integers(0, cfg.vocab, 6).astype(np.int32),

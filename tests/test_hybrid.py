@@ -144,7 +144,8 @@ def test_batching_serves_the_hybrid_and_mamba2():
         model = build_model(get_reduced_config(arch))
         assert model.supports_packed_prefill(64) and model.has_recurrent_state
         params = model.init(jax.random.PRNGKey(0))
-        eng = DecodeEngine(model, params, n_slots=2, cache_len=64, batching=True)
+        eng = DecodeEngine(model, params, n_slots=2, cache_len=64)
+        assert eng.batcher is not None, arch
         reqs = [Request(rid=i, prompt=np.arange(3 + 4 * i, dtype=np.int32) % 500, max_new=5)
                 for i in range(3)]
         eng.run(reqs)
@@ -157,7 +158,7 @@ def test_state_layers_refuse_paging_and_prefix_kv(kw):
     model = build_model(get_reduced_config("granite_4_0_h_micro"))
     params = model.init(jax.random.PRNGKey(0))
     with pytest.raises(ValueError, match="state snapshots"):
-        DecodeEngine(model, params, n_slots=2, cache_len=64, batching=True, **kw)
+        DecodeEngine(model, params, n_slots=2, cache_len=64, **kw)
 
 
 def test_engine_gauges_split_state_and_kv_bytes():

@@ -351,7 +351,7 @@ def small_model():
 def _engine(model, params, **kw):
     from repro.serving.engine import DecodeEngine
 
-    return DecodeEngine(model, params, n_slots=1, cache_len=64, prefix_kv=True, **kw)
+    return DecodeEngine(model, params, n_slots=1, cache_len=32, prefix_kv=True, **kw)
 
 
 def test_shipped_decode_bitwise_matches_reprefilled(small_model):
@@ -443,7 +443,7 @@ def test_engine_replica_admit_counts_shipped_bundles(small_model):
     assert exported is not None
 
     dst = EngineReplica(1, DecodeEngine(
-        model, params, n_slots=1, cache_len=64,
+        model, params, n_slots=1, cache_len=32,
         scheduler=None, topology=pod(1, 2),
         placement="nearest_spill", prefix_index=True, prefix_kv=True))
     assert dst.import_kv(*exported)              # the ship lands

@@ -121,7 +121,7 @@ def _engine(model, params, *, index):
     from repro.serving.scheduler import CNAScheduler
 
     return DecodeEngine(
-        model, params, n_slots=4, cache_len=64,
+        model, params, n_slots=4, cache_len=32,
         scheduler=CNAScheduler(fairness_threshold=0xF, topology=pod(2, 2)),
         placement="nearest_spill", prefix_index=index,
     )

@@ -1,6 +1,8 @@
 """Serving engine + CNA scheduler: correctness is admission-order-invariant,
 locality/throughput favor CNA, fairness is preserved."""
 
+import functools
+
 import jax
 import numpy as np
 import pytest
@@ -28,16 +30,20 @@ def _requests(cfg, n=8, domains=2, seed=0, plen=8, max_new=5):
     ]
 
 
+@functools.lru_cache(maxsize=None)
+def _jitted(model):
+    return jax.jit(model.prefill), jax.jit(model.decode_step)
+
+
 def _greedy_reference(model, params, prompt, n_new):
     """Free-running single-request decode (no batching)."""
     import jax.numpy as jnp
 
-    logits, cache = jax.jit(model.prefill)(params, {"tokens": jnp.asarray(prompt)[None]})
+    prefill, step = _jitted(model)
+    logits, cache = prefill(params, {"tokens": jnp.asarray(prompt)[None]})
     out = [int(jnp.argmax(logits[0]))]
     for _ in range(n_new - 1):
-        logits, cache = jax.jit(model.decode_step)(
-            params, cache, jnp.asarray([[out[-1]]], jnp.int32)
-        )
+        logits, cache = step(params, cache, jnp.asarray([[out[-1]]], jnp.int32))
         out.append(int(jnp.argmax(logits[0])))
     return out
 
@@ -45,7 +51,7 @@ def _greedy_reference(model, params, prompt, n_new):
 def test_outputs_match_unbatched_reference(small_model):
     cfg, model, params = small_model
     reqs = _requests(cfg, n=5, seed=1)
-    eng = DecodeEngine(model, params, n_slots=3, cache_len=64)
+    eng = DecodeEngine(model, params, n_slots=3, cache_len=32)
     eng.run(reqs)
     for r in reqs:
         ref = _greedy_reference(model, params, r.prompt, r.max_new)
@@ -60,7 +66,7 @@ def test_outputs_invariant_to_scheduler(small_model):
     outs = {}
     for name, sched in [("cna", CNAScheduler(fairness_threshold=0xF)), ("fifo", FIFOScheduler())]:
         reqs = [Request(r.rid, r.prompt, r.max_new, r.domain) for r in base]
-        DecodeEngine(model, params, n_slots=3, cache_len=64, scheduler=sched).run(reqs)
+        DecodeEngine(model, params, n_slots=3, cache_len=32, scheduler=sched).run(reqs)
         outs[name] = {r.rid: tuple(r.out) for r in reqs}
     assert outs["cna"] == outs["fifo"]
 
@@ -71,7 +77,7 @@ def test_cna_beats_fifo_on_locality_and_switch_cost(small_model):
     stats = {}
     for name, sched in [("cna", CNAScheduler(fairness_threshold=0xF)), ("fifo", FIFOScheduler())]:
         reqs = [Request(r.rid, r.prompt, r.max_new, r.domain) for r in base]
-        eng = DecodeEngine(model, params, n_slots=3, cache_len=64,
+        eng = DecodeEngine(model, params, n_slots=3, cache_len=32,
                            scheduler=sched, domain_switch_cost=8)
         eng.run(reqs)
         stats[name] = (eng.scheduler.metrics.locality, eng.scheduler.metrics.domain_switches, eng.sim_time)
@@ -155,7 +161,7 @@ def test_placement_engine_outputs_invariant_and_telemetry(small_model):
                         placement="nearest_spill")),
     ]:
         reqs = [Request(r.rid, r.prompt, r.max_new, r.domain) for r in base]
-        eng = DecodeEngine(model, params, n_slots=4, cache_len=64, **kw)
+        eng = DecodeEngine(model, params, n_slots=4, cache_len=32, **kw)
         eng.run(reqs)
         outs[name] = {r.rid: tuple(r.out) for r in reqs}
         if name == "placed":
@@ -234,7 +240,7 @@ def test_adaptive_scheduler_in_engine_feeds_controller(small_model):
     ctrl = AdaptiveController(initial=2, max_cap=8, window=4)
     sched = CNAScheduler(fairness_threshold=0xF, topology=pod(2, 2), max_active=ctrl)
     reqs = [Request(r.rid, r.prompt, r.max_new, r.domain) for r in base]
-    eng = DecodeEngine(model, params, n_slots=2, cache_len=64, scheduler=sched)
+    eng = DecodeEngine(model, params, n_slots=2, cache_len=32, scheduler=sched)
     eng.run(reqs)
     assert all(r.done for r in reqs)
     assert sched.controller is ctrl and ctrl.samples == 8
@@ -287,34 +293,11 @@ def test_prefix_kv_store_exact_prefix_lookup_and_lru():
         PrefixKVStore(capacity=0)
 
 
-def _greedy_reference_split(model, params, prompt, split, n_new):
-    """Free-running reference that prefills ``prompt[:split]`` and feeds the
-    rest token-by-token — the *incremental* decomposition prefix-KV reuse
-    performs.  (Batched prefill and incremental decode agree only to the
-    bf16 cache resolution, so greedy argmax on a random reduced config can
-    legitimately flip between decompositions; reuse reuses the *identical*
-    stored KV, so it must match the reference with the same split exactly.)"""
-    import jax.numpy as jnp
-
-    pf, st = jax.jit(model.prefill), jax.jit(model.decode_step)
-    if split >= len(prompt):
-        logits, cache = pf(params, {"tokens": jnp.asarray(prompt)[None]})
-    else:
-        logits, cache = pf(params, {"tokens": jnp.asarray(prompt[:split])[None]})
-        for t in prompt[split:]:
-            logits, cache = st(params, cache, jnp.asarray([[int(t)]], jnp.int32))
-    out = [int(jnp.argmax(logits[0]))]
-    for _ in range(n_new - 1):
-        logits, cache = st(params, cache, jnp.asarray([[out[-1]]], jnp.int32))
-        out.append(int(jnp.argmax(logits[0])))
-    return out
-
-
 def test_matched_len_aware_prefill_skips_cached_positions(small_model):
     """The ROADMAP unlock, pinned by counting prefill positions: with a
     PrefixKVStore the engine computes each shared prefix once; later prompts
-    sharing it prefill only their suffix — and decode exactly what the
-    incremental reference decodes."""
+    sharing it prefill only their suffix — and decode exactly what a
+    from-scratch prefill decodes."""
     cfg, model, params = small_model
     rng = np.random.default_rng(12)
     P = rng.integers(0, cfg.vocab, 12).astype(np.int32)
@@ -335,15 +318,15 @@ def test_matched_len_aware_prefill_skips_cached_positions(small_model):
     assert eng.prefill_positions == 16 + 16 + 4
     assert eng.reused_positions == 12
     assert eng.prefix_kv.hits == 1
-    splits = {0: 16, 1: 12, 2: 12}  # the decomposition each request ran
     for r in reqs:
-        ref = _greedy_reference_split(model, params, r.prompt, splits[r.rid], r.max_new)
+        ref = _greedy_reference(model, params, r.prompt, r.max_new)
         assert r.out[: r.max_new] == ref, f"rid={r.rid}"
 
 
 def test_prefill_reuse_on_conversation_extension(small_model):
     """A prompt that extends a previously served prompt resumes from its
-    stored cache directly (no boundary planting needed)."""
+    stored cache directly (no boundary planting needed), and decodes what a
+    from-scratch prefill decodes."""
     cfg, model, params = small_model
     rng = np.random.default_rng(13)
     first = rng.integers(0, cfg.vocab, 10).astype(np.int32)
@@ -355,7 +338,7 @@ def test_prefill_reuse_on_conversation_extension(small_model):
     before = eng.prefill_positions
     eng.run([r2])
     assert eng.prefill_positions - before == 5        # only the extension
-    ref = _greedy_reference_split(model, params, ext, len(first), r2.max_new)
+    ref = _greedy_reference(model, params, ext, r2.max_new)
     assert r2.out[: r2.max_new] == ref
 
 
@@ -498,14 +481,15 @@ def test_engine_auto_wires_controller_shedding(small_model):
 PHASES_NS = ("admit", "admit_insert", "admit_wait", "dispatch", "wait", "retire")
 
 
-@pytest.mark.parametrize("batching", [False, True])
-def test_engine_phase_counters(small_model, batching):
+@pytest.mark.parametrize("prefix_kv", [False, True])
+def test_engine_phase_counters(small_model, prefix_kv):
     """Every tick is counted and timed; its phases fit inside it; decode
     ticks and live lanes match what the decode call actually saw."""
     from repro.obs import MetricsRegistry
 
     cfg, model, params = small_model
-    eng = DecodeEngine(model, params, n_slots=3, cache_len=32, batching=batching)
+    eng = DecodeEngine(model, params, n_slots=3, cache_len=32,
+                       prefix_kv=True if prefix_kv else None)
     reg = MetricsRegistry()
     eng.register_metrics(reg)
     lanes_seen = []
@@ -546,7 +530,7 @@ def test_engine_phases_are_profiler_host_spans(small_model, tmp_path):
     from jax.profiler import ProfileData
 
     cfg, model, params = small_model
-    eng = DecodeEngine(model, params, n_slots=2, cache_len=32, batching=True)
+    eng = DecodeEngine(model, params, n_slots=2, cache_len=32)
     for r in _requests(cfg, n=2, plen=6, max_new=6):
         eng.submit(r)
     jax.profiler.start_trace(str(tmp_path))
