@@ -17,9 +17,11 @@ token mixers of ``attn``/``moe`` and ``ssd`` blocks run under
 splits device time by layer kind.
 
 Decode reads the KV cache as scan xs and writes each step's one new
-position after the scan (``DecoderLM._merge_kv``).  SSD and RG-LRU state,
-which each step rewrites whole, rides in the scan's carry and is updated
-at its layer index, so no stacked copy of it comes out of the scan.  With
+position after the scan (``DecoderLM._merge_kv``; where the TPU lays the
+cache out position-minor, the whole aligned tile block around it,
+``kv_write_block``).  SSD and RG-LRU state, which each step rewrites
+whole, rides in the scan's carry and is updated at its layer index, so no
+stacked copy of it comes out of the scan.  With
 the cache donated both writes are in place, so one cache stays resident.
 """
 
@@ -38,7 +40,7 @@ from .common import ParamBuilder, apply_rope, cross_entropy, embed_lookup, norm,
 from .mlp import declare_mlp, mlp_apply
 from .moe import declare_moe, moe_apply
 from .rglru import declare_rglru, rglru_block, rglru_block_step
-from .sharding import axis_shards, shard
+from .sharding import axis_shards, current_ctx, shard
 from .ssm import declare_ssd, ssd_block, ssd_block_step
 
 
@@ -399,6 +401,20 @@ def cfg_cache_dtype(cfg):
 # ---------------------------------------------------------------------------
 
 KV_AXES = ("batch", "kv_seq", "kv_heads", None)
+KV_TILE = 128   # positions in one TPU tile's lane dimension
+
+
+def kv_write_block(seq_len: int, head_dim: int) -> int:
+    """Positions one lane's decode KV write spans in a (…, S, kv, hd) cache.
+
+    Where the head size does not fill the 128 lanes of a TPU tile (stablelm's
+    80, granite-4.0-h's 64) the TPU compiler lays the cache out
+    position-minor: positions along the lanes, the head dim along the
+    sublanes.  A one-position write there touches one lane column of every
+    tile; the whole aligned ``KV_TILE`` block around it writes whole tiles.
+    A head size that is a multiple of 128 keeps the natural layout, where
+    one position is a run of whole rows: it is written alone."""
+    return KV_TILE if head_dim % KV_TILE and seq_len % KV_TILE == 0 else 1
 
 
 def block_cache_shape(cfg, kind: str, batch: int, cache_len: int):
@@ -780,13 +796,26 @@ class DecoderLM:
         """Write the (…, B, 1, kv, hd) new-token slices into the cache at
         ``pos`` (the ring slot for window configs), once per step.
 
-        Only the new position is written, by ``dynamic_update_slice``: once
-        for a scalar ``pos``, once per lane for per-lane ``pos``.  On a
-        donated cache XLA applies them in place.  A position past the
-        cache's end clamps onto its last entry; only an idle engine lane
-        gets there (a live sequence retires first), and its lane is
-        overwritten whole when it is claimed again.  (A scatter over the
-        lanes measured twice the step time on stablelm-3b; PERF.md.)
+        The write is a ``dynamic_update_slice``: once for a scalar ``pos``,
+        once per lane for per-lane ``pos``.  On a donated cache XLA applies
+        it in place.  A position past the cache's end clamps onto its last
+        entry; only an idle engine lane gets there (a live sequence retires
+        first), and its lane is overwritten whole when it is claimed again.
+        (A scatter over the lanes measured twice the step time on
+        stablelm-3b; PERF.md.)
+
+        Where the TPU lays the cache out position-minor
+        (``kv_write_block``), that one position is one lane column in every
+        (16, 128) tile of the cache's layers and heads, and those
+        partial-tile writes cost about 50 ns a tile (4 ms of stablelm-3b's
+        16 ms step).  There the ``kv_block_write`` kernel rewrites instead
+        the aligned 128-position block that holds each lane's slot, whole
+        tiles only, in place; the cache is bitwise what the one-position
+        write leaves.  It runs only with no mesh active: GSPMD cannot split
+        a kernel, and would gather the cache to run it.  (XLA's own
+        slice-select-update of that block measured slower than the
+        columns: 5.89 against 4.37 ms for a stablelm step's 16 writes;
+        PERF.md.)
 
         Under a mesh that shards the cache's ``kv_seq`` axis it stays a
         masked select over that axis: a dynamic-index write on a sharded dim
@@ -808,6 +837,11 @@ class DecoderLM:
                 if old.ndim == 5:
                     mask = mask[None]                            # (1, B, S, 1, 1)
             return jnp.where(mask, new, old)
+        if kv_write_block(s_max, old.shape[-1]) > 1 and current_ctx() is None:
+            from repro.kernels.kv_block_write import ops as kvw_ops
+
+            at = jnp.broadcast_to(jnp.clip(slot, 0, s_max - 1), old.shape[-4:-3])  # the clamp
+            return kvw_ops.write_kv_blocks(old, new, at)
         if slot.ndim == 0:
             start = (0,) * (old.ndim - 3) + (slot, 0, 0)
             return jax.lax.dynamic_update_slice(old, new, start)

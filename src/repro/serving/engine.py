@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..models.transformer import kv_write_block
 from ..obs.phases import PhaseClock
 from .batching import CountingJit, PrefillBatcher
 from .kvcache import SlotCache
@@ -591,21 +592,26 @@ class DecodeEngine:
         registry.gauge(f"{prefix}_decode_ticks", fn=lambda: self.decode_ticks)
         registry.gauge(f"{prefix}_decode_lanes", fn=lambda: self.decode_lanes)
         # what the slot cache holds: attention KV, and recurrent plus conv state
-        kv_bytes, state_bytes = self._cache_bytes()
+        kv_bytes, state_bytes, kv_block = self._cache_gauges()
         registry.gauge(f"{prefix}_kv_bytes", fn=lambda: kv_bytes)
         registry.gauge(f"{prefix}_state_bytes", fn=lambda: state_bytes)
+        # positions one lane's decode KV write spans: 128 for the whole-tile
+        # block write, 1 for the one-position write, 0 with no KV
+        registry.gauge(f"{prefix}_kv_write_block", fn=lambda: kv_block)
         if self._paged:
             # the memory-compaction claim as scrapeable numbers:
             # pages_total / pages_shared / pages_free / kv_bytes_held
             self.slots.register_into(registry, prefix=prefix)
 
-    def _cache_bytes(self) -> tuple[int, int]:
-        """(KV bytes, state bytes) of the slot cache's lanes, by each leaf's
-        declared logical axes: a leaf with a ``kv_seq`` axis is KV, any
-        other (recurrent state, conv tails) is state."""
+    def _cache_gauges(self) -> tuple[int, int, int]:
+        """(KV bytes, state bytes, KV write block) of the slot cache's
+        lanes, by each leaf's declared logical axes: a leaf with a
+        ``kv_seq`` axis is KV, any other (recurrent state, conv tails) is
+        state.  The write block is the most positions the decode step's
+        write spans in one lane of a KV leaf (``kv_write_block``)."""
         abstract = self.model.cache_abstract(self.n_slots, self.cache_len)
         logical = self.model.cache_logical(abstract)
-        kv = state = 0
+        kv = state = block = 0
         for key in abstract:
             if key == "pos":
                 continue
@@ -615,9 +621,10 @@ class DecodeEngine:
                 n = sds.size * sds.dtype.itemsize
                 if "kv_seq" in ax:
                     kv += n
+                    block = max(block, kv_write_block(sds.shape[ax.index("kv_seq")], sds.shape[-1]))
                 else:
                     state += n
-        return kv, state
+        return kv, state, block
 
     # -- decode ----------------------------------------------------------------
     def step(self):

@@ -1,9 +1,12 @@
 """The decode step's KV write: one position per lane, in place.
 
 ``DecoderLM._merge_kv`` writes each step's new position with
-``dynamic_update_slice`` and keeps the whole-cache masked select only under
-a mesh that shards the cache's sequence axis; the engine's tick donates the
-slot cache so that write lands in the buffer it came from, while the prefix
+``dynamic_update_slice``, or, where the TPU lays the cache out
+position-minor (head size not a multiple of 128, cache a multiple of 128
+positions), rewrites the whole aligned 128-position block around it with a
+Pallas kernel; it keeps the whole-cache masked select only under a mesh
+that shards the cache's sequence axis.  The engine's tick donates the slot
+cache so that write lands in the buffer it came from, while the prefix
 store's resume loop steps its shared entries without donating them."""
 
 import re
@@ -81,6 +84,62 @@ def test_write_is_bitwise_the_masked_select(small_model, monkeypatch, case):
         not np.array_equal(np.asarray(a), np.asarray(b))
         for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(cache))
     )
+
+
+# (window, head_dim, pos) over a 2-layer, 4-lane, 2-head cache of 1024
+# positions (a 256-position ring for the window config): the block edges;
+# positions past the end, which clamp onto the last entry; a ring's wrapped
+# positions (300 -> slot 44, 1000 -> 232); lanes in different blocks; one
+# scalar pos for every lane; an unstacked layer's (B, S, kv, hd) cache; and
+# head 128, which keeps the one-position write
+BLOCK_CASES = {
+    "edges": (0, 80, [0, 127, 128, 1023]),
+    "past_end": (0, 80, [1024, 1151, 4000, 5]),
+    "ring": (256, 80, [255, 256, 300, 1000]),
+    "mixed": (0, 64, [3, 700, 129, 1022]),
+    "scalar": (0, 80, 700),
+    "one_layer": (0, 80, [511, 0, 900, 1023]),
+    "head128": (0, 128, [0, 127, 128, 1023]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BLOCK_CASES))
+def test_block_write_is_bitwise_the_one_position_write(small_model, monkeypatch, case):
+    """Where the head size is not a multiple of 128 the write rewrites whole
+    128-position blocks, and leaves the cache bitwise what the
+    one-position write leaves; head 128 takes the one-position write."""
+    cfg, _, _ = small_model
+    window, head_dim, pos = BLOCK_CASES[case]
+    model = build_model(cfg.replace(window=window))
+    layers = () if case == "one_layer" else (2,)
+    s_max = window or 1024
+    k_old, k_new = jax.random.split(jax.random.PRNGKey(len(case)))
+    old = jax.random.normal(k_old, layers + (4, s_max, 2, head_dim)).astype(jnp.bfloat16)
+    new = jax.random.normal(k_new, layers + (4, 1, 2, head_dim)).astype(jnp.bfloat16)
+    pos = jnp.asarray(pos, jnp.int32)
+
+    prims = str(jax.make_jaxpr(model._merge_kv)(old, new, pos))
+    assert ("pallas_call" in prims) == (head_dim % 128 != 0), case
+    got = np.asarray(model._merge_kv(old, new, pos))
+    monkeypatch.setattr(transformer, "kv_write_block", lambda seq_len, head_dim: 1)
+    assert "pallas_call" not in str(jax.make_jaxpr(model._merge_kv)(old, new, pos))
+    want = np.asarray(model._merge_kv(old, new, pos))
+    np.testing.assert_array_equal(got, want)
+    assert not np.array_equal(got, np.asarray(old))
+
+
+@pytest.mark.parametrize("head_dim,block", [(80, 128), (128, 1)])
+def test_engine_gauges_its_kv_write_block(small_model, head_dim, block):
+    """``engine_kv_write_block`` reads the positions a lane's decode KV
+    write spans: the 128-position block for head 80, one for head 128."""
+    from repro.obs.registry import MetricsRegistry
+
+    cfg, _, _ = small_model
+    model = build_model(cfg.replace(head_dim=head_dim))
+    eng = DecodeEngine(model, model.init(jax.random.PRNGKey(0)), n_slots=2, cache_len=128)
+    reg = MetricsRegistry()
+    eng.register_metrics(reg)
+    assert reg.collect()["engine_kv_write_block"] == block
 
 
 def _requests(cfg, n, plen, max_new, seed=0):

@@ -24,14 +24,20 @@ from repro.kernels.ssd_scan.kernel import ssd_intra_bchlp
 
 @pytest.fixture(scope="module")
 def one_chip():
+    """One described v5e chip.  The programs compiled for it lower their
+    kernels for Mosaic, as on the chip, not in the CPU's interpret mode."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
+
+    from repro.kernels.kv_block_write import ops as kvw_ops
 
     try:
         topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler here, or the library is held
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(kvw_ops, "interpret_mode", lambda: False)
+        yield SingleDeviceSharding(topo.devices[0])
 
 
 def _sds(sharding, shape, dtype=jnp.float32):
@@ -179,3 +185,54 @@ def test_hybrid_admission_insert_writes_in_place(one_chip):
     cache_bytes = sum(s.size * s.dtype.itemsize for s in jax.tree.leaves(cache))
     assert mem.alias_size_in_bytes >= cache_bytes
     assert mem.temp_size_in_bytes < cache_bytes / 100
+
+
+# (config, preset, slots): stablelm-3b whole (32 heads of 80), the hybrid
+# whole (4 attention layers, 8 heads of 64) and granite-3-8b's one-chip
+# config (8 heads of 128), each over a 1024-position slot cache
+KV_WRITE_CASES = {
+    "stablelm-3b": ("stablelm_3b", None, 8),
+    "granite-4.0-h-micro": ("granite_4_0_h_micro", None, 32),
+    "granite-3-8b": ("granite_3_8b", "one_chip", 8),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KV_WRITE_CASES))
+def test_decode_kv_write_follows_the_cache_layout(one_chip, case):
+    """The donated decode step's KV write on the chip's compiler.  Head 80
+    and head 64 caches are laid out position-minor, the premise of
+    ``kv_write_block``: there the whole-tile kernel writes them, on the
+    cache's own bytes (no copy of a KV leaf).  Head 128 keeps its natural
+    layout and the one-position ``dynamic-update-slice``.  Either way the
+    temporaries stay under 0.7 GB: no second cache."""
+    from repro.configs.base import get_config, get_preset_config
+    from repro.models.registry import build_model
+    from repro.models.transformer import kv_write_block
+
+    arch, preset, slots = KV_WRITE_CASES[case]
+    cfg = get_preset_config(arch, preset) if preset else get_config(arch)
+    model = build_model(cfg)
+    place = lambda s: _sds(one_chip, s.shape, s.dtype)  # noqa: E731
+    params = jax.tree.map(place, model.abstract_params())
+    cache = jax.tree.map(place, model.cache_abstract(slots, 1024))
+    cache["pos"] = _sds(one_chip, (slots,), jnp.int32)
+    tokens = _sds(one_chip, (slots, 1), jnp.int32)
+    compiled = jax.jit(model.decode_step, donate_argnums=(1,)).lower(params, cache, tokens).compile()
+    hlo = compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.7e9
+
+    kv_dims = {s.shape for s in jax.tree.leaves(cache) if s.ndim == 5 and s.shape[2] == 1024}
+    assert len(kv_dims) == 1, kv_dims
+    (dims,) = kv_dims
+    shape = ",".join(map(str, dims))
+    minor = "2,4,3,1,0" if kv_write_block(1024, cfg.hd) > 1 else "4,3,2,1,0"
+    params_at = re.findall(rf"= bf16\[{shape}\]\{{([\d,]+):T[^}}]*\}} parameter\(", hlo)
+    assert params_at and set(params_at) == {minor}, (case, params_at)
+    for d in (dims, (dims[0], dims[1], dims[3], dims[4], dims[2])):
+        assert not re.search(rf"= bf16\[{','.join(map(str, d))}\]\{{[^}}]*\}} copy\(", hlo), d
+    if minor == "2,4,3,1,0":
+        assert hlo.count('custom_call_target="tpu_custom_call"') >= 2
+        assert "kv_block_write" in hlo
+    else:
+        assert "kv_block_write" not in hlo
+        assert re.search(rf"= bf16\[{shape}\]\{{[^}}]*\}} dynamic-update-slice\(", hlo)
